@@ -9,6 +9,8 @@
 #   override with LAUNCH_DEVICES=N for sharding experiments
 # - f32 default dtype (the wire format and every reference chain is f32;
 #   an x64 default would silently double apply costs)
+# - the platform is JAX's own choice (JAX_PLATFORMS is left as the caller
+#   set it), so a launch on a TPU host runs on the chip
 # - tcmalloc via LD_PRELOAD when present (steadier allocation tails than
 #   glibc malloc on the 1-core CI box); silently skipped when absent
 set -euo pipefail
@@ -16,7 +18,6 @@ set -euo pipefail
 DEVICES="${LAUNCH_DEVICES:-1}"
 export XLA_FLAGS="--xla_force_host_platform_device_count=${DEVICES}${XLA_FLAGS:+ $XLA_FLAGS}"
 export JAX_DEFAULT_DTYPE_BITS="${JAX_DEFAULT_DTYPE_BITS:-32}"
-export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 
 for lib in /usr/lib/x86_64-linux-gnu/libtcmalloc_minimal.so.4 \
            /usr/lib/x86_64-linux-gnu/libtcmalloc.so.4 \

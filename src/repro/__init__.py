@@ -7,7 +7,7 @@ Layers:
                           paper's LSTM).
 - ``repro.optim``       — RMSprop/SGD/Adam + gradient compression.
 - ``repro.distributed`` — pjit/shard_map production mapping of the JSDoop schedule.
-- ``repro.kernels``     — Pallas TPU kernels (validated in interpret mode).
+- ``repro.kernels``     — Pallas TPU kernels (compiled; interpret on request).
 - ``repro.launch``      — mesh / dry-run / train / serve entry points.
 """
 __version__ = "1.0.0"

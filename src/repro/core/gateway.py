@@ -82,6 +82,7 @@ from collections import deque
 from typing import Deque, Dict, Optional, Tuple
 
 from repro.checkpoint import serialize
+from repro.compile_cache import place_compile_cache
 from repro.core import wsframing
 from repro.core.aggregation import PolicyLike, make_policy
 from repro.core.dataserver import DataServer
@@ -2181,6 +2182,9 @@ def _smoke_ws_dialect(args) -> None:
                      "--dialect", dialect,
                      "--n-versions", str(args.n_versions),
                      "--n-mb", str(args.n_mb),
+                     # paced, so neither process can finish the whole run
+                     # (and the server exit) before the other has started
+                     "--task-delay", "0.2",
                      "--expect-final", str(args.n_versions)],
                     env=os.environ.copy()))
             rcs = [v.wait(timeout=90) for v in volunteers]
@@ -2216,13 +2220,15 @@ def _smoke_browser_thin(args) -> None:
                  "--policy", policy,
                  "--n-versions", str(args.n_versions),
                  "--n-mb", str(args.n_mb),
+                 # both paced, as in the ws-dialect leg
+                 "--task-delay", "0.2",
                  "--expect-final", str(n_updates)],
                 env=os.environ.copy())
             tcp = subprocess.Popen(
                 [sys.executable, "-m", "repro.core.gateway", "--volunteer",
                  "--port", str(port), "--vid", "tcp1", "--policy", policy,
                  "--n-versions", str(args.n_versions),
-                 "--n-mb", str(args.n_mb),
+                 "--n-mb", str(args.n_mb), "--task-delay", "0.2",
                  "--expect-final", str(n_updates)],
                 env=os.environ.copy())
             rcs = [browser.wait(timeout=90), tcp.wait(timeout=90)]
@@ -2238,76 +2244,97 @@ def _smoke_browser_thin(args) -> None:
           f"v{n_updates}; browser pushed zero PublishModel frames")
 
 
+def serve_real_run(problem: TrainingProblem, vids, *, n_versions: int,
+                   policy: PolicyLike = "staleness:2", timeout: float = 600.0):
+    """Serve one real-apply run from THIS process and drive ``vids`` as
+    real-compute volunteers over loopback TCP, one thread each.
+
+    The server and every volunteer share this process, so on an accelerator
+    host the applier's hot state and the volunteers' gradient steps run on
+    the one device the process holds — a second process on the same chip
+    would fail or hang. ``vids[0]`` fetches the final model over the wire
+    (``FetchModel``) before saying goodbye. Returns ``(server, results,
+    final_blob)`` with ``results[vid] = (final_version, tasks_done)``; the
+    server is closed, its applier state still readable."""
+    n_updates = make_policy(policy).n_updates(problem, n_versions)
+    server = GatewayServer(problem, n_versions=n_versions, policy=policy,
+                           real_apply=True)
+    server.start()
+    results: Dict[str, Tuple[int, int]] = {}
+    errors: list = []
+
+    def drive(vid: str) -> None:
+        try:
+            tr = SocketTransport("127.0.0.1", server.port, vid)
+            try:
+                results[vid] = run_volunteer(tr, vid, n_updates,
+                                             policy=policy, problem=problem)
+            finally:
+                tr.close()
+        except Exception as e:           # surfaced on the calling thread
+            errors.append(e)
+
+    try:
+        threads = [threading.Thread(target=drive, args=(v,), daemon=True)
+                   for v in vids[1:]]
+        for th in threads:
+            th.start()
+        tr = SocketTransport("127.0.0.1", server.port, vids[0])
+        try:
+            results[vids[0]] = run_volunteer(tr, vids[0], n_updates,
+                                             policy=policy, problem=problem)
+            final_blob = tr.call(FetchModel(n_updates)).blob
+        finally:
+            tr.close()
+        for th in threads:
+            th.join(timeout=timeout)
+            if th.is_alive():
+                raise RuntimeError("real volunteer deadlocked")
+    finally:
+        server.close()
+    if errors:
+        raise errors[0]
+    return server, results, final_blob
+
+
 def _smoke_real_applier(args) -> None:
     """Leg 7 — the REAL JAX applier over the socket: (a) one real-compute
-    volunteer against a ``--real-apply`` server PROCESS must land on a final
-    model BIT-IDENTICAL to ``sequential_async`` (fetched back over the wire);
-    (b) three concurrent real-compute volunteers must finish the run with
-    contiguous versions — the combining-lock drain path under real races."""
+    volunteer must land on a final model BIT-IDENTICAL to
+    ``sequential_async`` (fetched back over the wire); (b) three concurrent
+    real-compute volunteers must finish the run with contiguous versions —
+    the combining-lock drain path under real races. The server runs in this
+    process (``serve_real_run``), so the leg never puts two processes on one
+    accelerator; it is a CPU protocol test, and ``chip_smoke.py`` is the
+    same run at full width on the chip."""
     from repro.core.mapreduce import sequential_async
+    import jax
     import numpy as np
-    policy = "staleness:2"
     problem = _real_problem()
     n_versions = 2                       # 2 * n_mb(4) = 8 updates
-    n_updates = make_policy(policy).n_updates(problem, n_versions)
-    extra = ("--policy", policy, "--real-apply")
+    n_updates = make_policy("staleness:2").n_updates(problem, n_versions)
 
-    def serve_run(vids):
-        with tempfile.TemporaryDirectory() as td:
-            port_file = os.path.join(td, "gw.port")
-            proc = _spawn_server(
-                args, port_file,
-                extra=extra + ("--n-versions", str(n_versions)))
-            try:
-                port = _wait_port(port_file, proc)
-                results: Dict[str, Tuple[int, int]] = {}
-
-                def drive(vid: str) -> None:
-                    tr = SocketTransport("127.0.0.1", port, vid)
-                    results[vid] = run_volunteer(tr, vid, n_updates,
-                                                 policy=policy,
-                                                 problem=problem)
-                    tr.close()
-
-                threads = [threading.Thread(target=drive, args=(v,),
-                                            daemon=True) for v in vids[1:]]
-                for th in threads:
-                    th.start()
-                # the first vid runs on THIS thread and fetches the final
-                # model over the wire before saying goodbye
-                tr = SocketTransport("127.0.0.1", port, vids[0])
-                results[vids[0]] = run_volunteer(tr, vids[0], n_updates,
-                                                 policy=policy,
-                                                 problem=problem)
-                for th in threads:
-                    th.join(timeout=120)
-                    assert not th.is_alive(), "real volunteer deadlocked"
-                final_blob = tr.call(FetchModel(n_updates)).blob
-                tr.close()
-                rc = proc.wait(timeout=15)
-            finally:
-                if proc.poll() is None:
-                    proc.kill()
-        assert rc == 0, f"gateway server exited {rc}"
+    def run(vids):
+        server, results, blob = serve_real_run(problem, vids,
+                                               n_versions=n_versions)
         finals = [results[v][0] for v in sorted(results)]
         assert finals == [n_updates] * len(vids), finals
-        return final_blob
+        assert server.observed_version == n_updates
+        return blob
 
     # (a) one volunteer: commit order is serialized, so the wire-fetched
     # final model must BIT-match the sequential reference
-    blob = serve_run(["r0"])
+    blob = run(["r0"])
     ref_p, ref_s, _ = sequential_async(problem, n_updates=n_updates)
-    import jax
     same = jax.tree.all(jax.tree.map(
         lambda a, b: bool((np.asarray(a) == np.asarray(b)).all()),
         blob, (ref_p, ref_s)))
     assert same, "real-apply final model != sequential_async bits"
     # (b) three racing volunteers: liveness + a contiguous final version
-    serve_run(["r0", "r1", "r2"])
-    print(f"# OK gateway smoke [real-applier]: --real-apply served real JAX "
-          f"applies over the socket — 1-volunteer run bit-matched "
-          f"sequential_async at v{n_updates}; 3 racing volunteers finished "
-          f"the drained run")
+    run(["r0", "r1", "r2"])
+    print(f"# OK gateway smoke [real-applier]: in-process real-apply server "
+          f"served real JAX applies over the socket — 1-volunteer run "
+          f"bit-matched sequential_async at v{n_updates}; 3 racing "
+          f"volunteers finished the drained run")
 
 
 def _smoke_cluster(args) -> int:
@@ -2447,6 +2474,7 @@ def main(argv=None) -> int:
     ap.add_argument("--expect-final", type=int, default=None)
     ap.add_argument("--timeout", type=float, default=60.0)
     args = ap.parse_args(argv)
+    place_compile_cache()
     if args.serve:
         rc = _serve(args)
     elif args.volunteer:

@@ -9,7 +9,8 @@ the rescaling is VPU work. Tiles are 128-aligned for the MXU.
 Grid: (B, Kv, Sq/blk_q); the kv loop is a fori_loop inside the kernel with
 a causal early-exit bound, so the quadratic term only pays for the lower
 triangle. GQA is handled by folding the G = H/Kv group dim into the q tile
-rows ([blk_q * G, hd] q block per kv head).
+rows ([G * blk_q, hd] q block per kv head; blk_q a multiple of 8 keeps the
+fold a tile-aligned reshape on the TPU).
 
 Forward-only: training uses the jnp flash path (layers.flash_attention,
 custom_vjp); this kernel is the serving/prefill fast path. Validated in
@@ -30,15 +31,17 @@ NEG_INF = -1e30
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *, blk_q: int, blk_k: int,
                seq_kv: int, causal: bool, window: int, scale: float):
     """Block shapes (leading B/Kv dims are size-1 grid blocks):
-      q [1, blk_q, G, hd] -> folded to [blk_q*G, hd]
-      k [1, Skv, hd]   v [1, Skv, hd]   (full kv row of this head in VMEM;
-                                         fori_loop slices blk_k tiles)
-      o [1, blk_q, G, hd]
+      q [1, 1, G, blk_q, hd] -> folded to [G*blk_q, hd] (row r is query
+                                position q_start + r % blk_q)
+      k [1, 1, Skv, hd]   v [1, 1, Skv, hd]   (full kv row of this head in
+                                              VMEM; the loop reads blk_k
+                                              tiles with pl.ds)
+      o [1, 1, G, blk_q, hd]
     """
     iq = pl.program_id(2)
-    q = q_ref[0, 0].astype(jnp.float32)                # [blk_q, G, hd]
-    bq, G, hd = q.shape
-    q2 = q.reshape(bq * G, hd) * scale
+    G, bq, hd = q_ref.shape[2:]
+    rows = G * bq
+    q2 = q_ref[0, 0].astype(jnp.float32).reshape(rows, hd) * scale
 
     q_start = iq * blk_q
     # causal upper bound on kv tiles this q tile can see
@@ -47,41 +50,39 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *, blk_q: int, blk_k: int,
     else:
         hi = seq_kv
     n_tiles = pl.cdiv(hi, blk_k)
+    qpos = q_start + jax.lax.broadcasted_iota(
+        jnp.int32, (G, bq, blk_k), 1).reshape(rows, blk_k)
 
     def body(t, carry):
         m, l, acc = carry
-        k_start = t * blk_k
-        k = jax.lax.dynamic_slice(k_ref[0, 0], (k_start, 0),
-                                  (blk_k, hd)).astype(jnp.float32)
-        v = jax.lax.dynamic_slice(v_ref[0, 0], (k_start, 0),
-                                  (blk_k, hd)).astype(jnp.float32)
+        k_start = pl.multiple_of(t * blk_k, blk_k)
+        k = k_ref[0, 0, pl.ds(k_start, blk_k), :].astype(jnp.float32)
+        v = v_ref[0, 0, pl.ds(k_start, blk_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(q2, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         # mask: causal + sliding window + kv-padding
-        qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, G), 0)
-        qpos = qpos.reshape(bq * G, 1)
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, blk_k), 1)
+        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (rows, blk_k), 1)
         ok = kpos < seq_kv
         if causal:
             ok &= kpos <= qpos
         if window > 0:
             ok &= kpos > qpos - window
         s = jnp.where(ok, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1)
-        acc_new = acc * alpha[:, None] + jax.lax.dot_general(
+        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = acc * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
-    m0 = jnp.full((bq * G,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq * G,), jnp.float32)
-    a0 = jnp.zeros((bq * G, hd), jnp.float32)
+    m0 = jnp.full((rows, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((rows, 1), jnp.float32)
+    a0 = jnp.zeros((rows, hd), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, n_tiles, body, (m0, l0, a0))
     l = jnp.where(l == 0.0, 1.0, l)
-    out = (acc / l[:, None]).reshape(bq, G, hd)
+    out = (acc / l).reshape(G, bq, hd)
     o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
@@ -90,7 +91,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *, blk_q: int, blk_k: int,
     static_argnames=("causal", "window", "blk_q", "blk_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     blk_q: int = 128, blk_k: int = 128,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """q [B,Sq,H,hd]; k/v [B,Skv,Kv,hd]; GQA G=H/Kv. Self-attention with
     q aligned to the end of kv (training/prefill: Sq == Skv)."""
     B, Sq, H, hd = q.shape
@@ -104,12 +105,12 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     bk = min(blk_k, Skv)
     pad_k = pl.cdiv(Skv, bk) * bk - Skv
 
-    # layout: q [B, Kv, Sq, G, hd]; kv [B, Kv, Skv, hd]
-    qr = jnp.moveaxis(q.reshape(B, Sq, Kv, G, hd), 1, 2)
+    # layout: q [B, Kv, G, Sq, hd]; kv [B, Kv, Skv, hd]
+    qr = jnp.transpose(q.reshape(B, Sq, Kv, G, hd), (0, 2, 3, 1, 4))
     kr = jnp.moveaxis(k, 2, 1)
     vr = jnp.moveaxis(v, 2, 1)
     if pad_q:
-        qr = jnp.pad(qr, ((0, 0), (0, 0), (0, pad_q), (0, 0), (0, 0)))
+        qr = jnp.pad(qr, ((0, 0), (0, 0), (0, 0), (0, pad_q), (0, 0)))
     if pad_k:
         kr = jnp.pad(kr, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         vr = jnp.pad(vr, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
@@ -120,14 +121,14 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                           causal=causal, window=window, scale=scale),
         grid=(B, Kv, Sq_p // bq),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, G, hd), lambda b, h, i: (b, h, i, 0, 0)),
+            pl.BlockSpec((1, 1, G, bq, hd), lambda b, h, i: (b, h, 0, i, 0)),
             pl.BlockSpec((1, 1, Skv_p, hd), lambda b, h, i: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, Skv_p, hd), lambda b, h, i: (b, h, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, G, hd),
-                               lambda b, h, i: (b, h, i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Kv, Sq_p, G, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, bq, hd),
+                               lambda b, h, i: (b, h, 0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Kv, G, Sq_p, hd), q.dtype),
         interpret=interpret,
     )(qr, kr, vr)
-    out = out[:, :, :Sq]                                # strip q padding
-    return jnp.moveaxis(out, 2, 1).reshape(B, Sq, H, hd)
+    out = out[:, :, :, :Sq]                             # strip q padding
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, Sq, H, hd)

@@ -7,12 +7,15 @@ One kernel invocation computes, for a batch tile and a hidden tile:
     h' = sigmoid(o) * tanh(c')  (VPU: fused gate elementwise)
 
 vs. the unfused path (tfjs semantics) which materializes z in HBM and
-launches 6 elementwise kernels. The weight is laid out [Din+H, 4, H] so one
+launches 6 elementwise kernels. The weight is laid out [Din+H, 4, Hp] so one
 hidden tile covers all four gates of the same cells, keeping the gate
 nonlinearity local to the block.
 
-TPU notes: tiles default to (8, 128)-aligned; the paper's H=50 pads to one
-lane tile. All accumulation is fp32 regardless of input dtype.
+TPU notes: Mosaic splits the [bT, 4*hT] gate matmul into four gates only
+when hT is a whole number of 128-lane tiles, so the wrapper pads every gate
+to Hp = a multiple of ``block_h`` (the paper's H=50 becomes one 128-lane
+tile); the padded cells compute zeros and are sliced off. All accumulation
+is fp32 regardless of input dtype.
 """
 from __future__ import annotations
 
@@ -48,20 +51,24 @@ def _cell_kernel(xh_ref, w_ref, b_ref, c_ref, h_out_ref, c_out_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_b", "block_h", "interpret"))
 def lstm_cell(x, h, c, kernel, bias, *, block_b: int = 128,
-              block_h: int = 128, interpret: bool = True):
+              block_h: int = 128, interpret: bool = False):
     """x [B, Din]; h, c [B, H]; kernel [(Din+H), 4H]; bias [4H].
 
+    ``block_h`` must be a multiple of 128 to compile for the TPU.
     Returns (h_new, c_new), matching ref.lstm_cell (keras gate order).
     """
     B, H = h.shape
     dxh = kernel.shape[0]
-    w4 = kernel.reshape(dxh, 4, H)
-    b4 = bias.reshape(4, H)
+    Hp = pl.cdiv(H, block_h) * block_h
+    pad = Hp - H
+    w4 = jnp.pad(kernel.reshape(dxh, 4, H), ((0, 0), (0, 0), (0, pad)))
+    b4 = jnp.pad(bias.reshape(4, H), ((0, 0), (0, pad)))
+    cp = jnp.pad(c, ((0, 0), (0, pad)))
     xh = jnp.concatenate([x, h], axis=-1)
 
     bB = min(block_b, B)
-    bH = min(block_h, H)
-    grid = (pl.cdiv(B, bB), pl.cdiv(H, bH))
+    bH = block_h
+    grid = (pl.cdiv(B, bB), Hp // bH)
 
     h_new, c_new = pl.pallas_call(
         _cell_kernel,
@@ -76,8 +83,8 @@ def lstm_cell(x, h, c, kernel, bias, *, block_b: int = 128,
             pl.BlockSpec((bB, bH), lambda ib, ih: (ib, ih)),
             pl.BlockSpec((bB, bH), lambda ib, ih: (ib, ih)),
         ],
-        out_shape=[jax.ShapeDtypeStruct((B, H), h.dtype),
-                   jax.ShapeDtypeStruct((B, H), c.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((B, Hp), h.dtype),
+                   jax.ShapeDtypeStruct((B, Hp), c.dtype)],
         interpret=interpret,
-    )(xh, w4, b4, c)
-    return h_new, c_new
+    )(xh, w4, b4, cp)
+    return h_new[:, :H], c_new[:, :H]

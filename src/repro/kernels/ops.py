@@ -1,47 +1,14 @@
-"""Jit'd public wrappers over the Pallas kernels.
+"""Public entry points of the Pallas kernels.
 
-On this CPU container every kernel runs with ``interpret=True`` (the body is
-executed in Python on CPU); on a real TPU set ``interpret=False`` (the
-default flips automatically when a TPU backend is present).
+Every kernel compiles for the TPU unless the caller passes
+``interpret=True``, which runs the kernel body in Python (the CPU tests do).
+Nothing picks interpret mode on the caller's behalf: on a backend other than
+the TPU a compiled call fails at lowering instead of running slowly.
 """
-from __future__ import annotations
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.lstm_cell import lstm_cell
+from repro.kernels.rmsnorm import rmsnorm
+from repro.kernels.ternary import ternary_decode, ternary_encode
 
-import jax
-import jax.numpy as jnp
-
-from repro.kernels import flash_attention as _fa
-from repro.kernels import lstm_cell as _lstm
-from repro.kernels import rmsnorm as _rms
-from repro.kernels import ternary as _tern
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def lstm_cell(x, h, c, kernel, bias, *, interpret: bool | None = None):
-    itp = _default_interpret() if interpret is None else interpret
-    return _lstm.lstm_cell(x, h, c, kernel, bias, interpret=itp)
-
-
-def rmsnorm(x, scale, *, eps: float = 1e-6, interpret: bool | None = None):
-    itp = _default_interpret() if interpret is None else interpret
-    return _rms.rmsnorm(x, scale, eps=eps, interpret=itp)
-
-
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    blk_q: int = 128, blk_k: int = 128,
-                    interpret: bool | None = None):
-    itp = _default_interpret() if interpret is None else interpret
-    return _fa.flash_attention(q, k, v, causal=causal, window=window,
-                               blk_q=blk_q, blk_k=blk_k, interpret=itp)
-
-
-def ternary_encode(g_flat, scale, *, interpret: bool | None = None):
-    itp = _default_interpret() if interpret is None else interpret
-    return _tern.ternary_encode(g_flat, scale, interpret=itp)
-
-
-def ternary_decode(packed, scale, *, interpret: bool | None = None):
-    itp = _default_interpret() if interpret is None else interpret
-    return _tern.ternary_decode(packed, scale, interpret=itp)
+__all__ = ["flash_attention", "lstm_cell", "rmsnorm", "ternary_decode",
+           "ternary_encode"]

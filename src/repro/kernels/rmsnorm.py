@@ -23,7 +23,7 @@ def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
 @functools.partial(jax.jit,
                    static_argnames=("eps", "block_rows", "interpret"))
 def rmsnorm(x, scale, *, eps: float = 1e-6, block_rows: int = 256,
-            interpret: bool = True):
+            interpret: bool = False):
     """x [..., D]; scale [D]. Returns RMS-normalized x (same dtype)."""
     orig_shape = x.shape
     D = orig_shape[-1]

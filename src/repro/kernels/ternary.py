@@ -39,7 +39,7 @@ def _decode_kernel(p_ref, s_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def ternary_encode(g_flat, scale, *, block_rows: int = 4096,
-                   interpret: bool = True):
+                   interpret: bool = False):
     """g_flat [N] (N % 4 == 0), scale scalar fp32 -> packed uint8 [N/4]."""
     n = g_flat.shape[0]
     assert n % 4 == 0, n
@@ -59,7 +59,7 @@ def ternary_encode(g_flat, scale, *, block_rows: int = 4096,
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def ternary_decode(packed, scale, *, block_rows: int = 4096,
-                   interpret: bool = True):
+                   interpret: bool = False):
     """packed uint8 [N/4], scale scalar -> g_flat fp32 [N]."""
     rows = packed.shape[0]
     bR = min(block_rows, rows)

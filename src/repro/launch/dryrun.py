@@ -29,6 +29,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 import repro.configs as C
 from repro.launch import hlo_cost
@@ -144,7 +145,7 @@ def lower_one(arch: str, shape_name: str, mesh, *, rt: Optional[Runtime] = None,
 
     opt = rmsprop(0.1) if optimizer == "rmsprop" else adamw(1e-4)
 
-    with MESH.compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             b = ST.bind_train(mesh, cfg, rt, opt, shape, policy=policy,
                               num_microbatches=num_microbatches, donate=False)
@@ -169,7 +170,7 @@ def lower_one(arch: str, shape_name: str, mesh, *, rt: Optional[Runtime] = None,
         t_compile = time.time() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    cost = hlo_cost.xla_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     # loop-aware re-analysis: XLA's cost_analysis counts while bodies once
     # (see hlo_cost docstring); ours scales by known_trip_count.
@@ -256,7 +257,8 @@ def elastic_plan(arch: str, shape_name: str, *, steps=((4, 16), (8, 16),
     """
     recs = []
     for shape_dp in steps:
-        mesh = MESH.compat_make_mesh(shape_dp, ("data", "model"))
+        mesh = jax.make_mesh(shape_dp, ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         rec = lower_one(arch, shape_name, mesh)
         print(f"[elastic] dp={shape_dp[0]:3d} x tp={shape_dp[1]} "
               f"compile={rec['compile_s']:.1f}s "

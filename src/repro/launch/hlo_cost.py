@@ -50,16 +50,6 @@ _OPERAND_RE = re.compile(r"%([\w.\-]+)")
 _CDIM_RE = re.compile(r"lhs_contracting_dims=\{([\d,]*)\}")
 
 
-def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """Normalize ``Compiled.cost_analysis()`` across jax versions: 0.4.x
-    returns a one-element list of dicts (per device assignment), newer jax
-    returns the dict directly."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def shape_bytes(type_str: str) -> int:
     total = 0
     for m in _SHAPE_RE.finditer(type_str):

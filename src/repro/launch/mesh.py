@@ -9,43 +9,21 @@ while tests and benches see the real single CPU device.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 exposes explicit axis types; 0.4.x (floor: 0.4.37) does not
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
-
-
-def compat_make_mesh(shape, axis_names):
-    """jax.make_mesh across the supported jax range: pass axis_types=Auto when
-    the installed jax knows about it, plain make_mesh otherwise (0.4.x treats
-    every axis as auto already)."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axis_names,
-                             axis_types=(AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(shape, axis_names)
-
-
-def compat_set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh:
-    ``jax.set_mesh`` where it exists (jax >= 0.6), else the Mesh object
-    itself (the 0.4.x ``with mesh:`` idiom)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Whatever this host actually has (1 CPU device in this container):
     the degenerate (1, 1) mesh used by the real train/serve drivers and tests."""
     n = len(jax.devices())
-    return compat_make_mesh((n, 1), ("data", "model"))
+    return jax.make_mesh((n, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 # TPU v5e hardware constants (per chip) — the roofline denominators.

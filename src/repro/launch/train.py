@@ -23,6 +23,7 @@ import numpy as np
 
 import repro.configs as C
 from repro.checkpoint.store import CheckpointStore
+from repro.compile_cache import place_compile_cache
 from repro.configs.base import InputShape
 from repro.distributed import steps as ST
 from repro.distributed import sharding as SH
@@ -121,6 +122,7 @@ def main(argv=None) -> int:
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
     args = ap.parse_args(argv)
+    place_compile_cache()
     if args.paper:
         return run_paper(args)
     if not args.arch:

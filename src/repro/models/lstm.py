@@ -37,7 +37,7 @@ def init_lstm_model(key, cfg, vocab: int, dtype=jnp.float32):
     return {"layers": layers, "head": head}
 
 
-def lstm_cell(p, x, hc, *, use_pallas: bool = False, interpret: bool = True):
+def lstm_cell(p, x, hc, *, use_pallas: bool = False, interpret: bool = False):
     """One step. x [B, d_in]; hc = (h [B,H], c [B,H])."""
     h, c = hc
     if use_pallas:
@@ -51,7 +51,7 @@ def lstm_cell(p, x, hc, *, use_pallas: bool = False, interpret: bool = True):
 
 
 def apply_lstm_model(params, onehot, *, use_pallas: bool = False,
-                     interpret: bool = True):
+                     interpret: bool = False):
     """onehot [B, T, V] -> next-char logits [B, V]."""
     B = onehot.shape[0]
     x_seq = jnp.moveaxis(onehot, 1, 0)                        # [T, B, V]
@@ -71,7 +71,7 @@ def apply_lstm_model(params, onehot, *, use_pallas: bool = False,
     return last @ params["head"]["w"] + params["head"]["b"]
 
 
-def lstm_loss(params, batch, *, use_pallas: bool = False, interpret: bool = True):
+def lstm_loss(params, batch, *, use_pallas: bool = False, interpret: bool = False):
     """batch: {"x": one-hot [B,T,V], "y": int labels [B]} -> mean CE (nats)."""
     logits = apply_lstm_model(params, batch["x"], use_pallas=use_pallas,
                               interpret=interpret)

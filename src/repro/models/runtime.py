@@ -39,8 +39,8 @@ class Runtime:
                                    # (Megatron-SP AG-at-entry), instead of XLA
                                    # re-gathering x for every projection
     # kernels
-    use_pallas: bool = False      # route hot ops through Pallas kernels (interpret on CPU)
-    pallas_interpret: bool = True
+    use_pallas: bool = False      # route hot ops through Pallas kernels
+    pallas_interpret: bool = False  # True only off the TPU (tests)
 
     def cf(self, cfg) -> float:
         return self.capacity_factor or cfg.moe.capacity_factor

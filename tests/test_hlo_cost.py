@@ -60,7 +60,7 @@ def test_unrolled_matches_xla_cost_analysis():
                                jax.ShapeDtypeStruct((N, N), jnp.float32))
     compiled = lowered.compile()
     ours = hlo_cost.analyze(compiled.as_text())["flops"]
-    xla = float(hlo_cost.xla_cost_analysis(compiled).get("flops", 0))
+    xla = float(compiled.cost_analysis().get("flops", 0))
     assert ours == pytest.approx(xla, rel=0.01) == pytest.approx(
         4 * 2 * N ** 3, rel=0.01)
 

@@ -66,8 +66,9 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
 import json, jax
 from repro.launch import dryrun as DR
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((4, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 for arch, shape in [("stablelm-1.6b", "train_4k"),
                     ("falcon-mamba-7b", "decode_32k")]:
     rec = DR.lower_one(arch, shape, mesh)

@@ -1,4 +1,5 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode."""
+"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode
+(the compiled kernels are checked in test_tpu_compile.py and chip_smoke.py)."""
 from __future__ import annotations
 
 import jax
@@ -102,3 +103,22 @@ def test_lstm_model_pallas_path_matches_jnp():
     l1 = LSTM.lstm_loss(params, batch, use_pallas=False)
     l2 = LSTM.lstm_loss(params, batch, use_pallas=True, interpret=True)
     np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5)
+
+
+@pytest.mark.parametrize("name", ["lstm_cell", "rmsnorm", "flash_attention",
+                                  "ternary_encode", "ternary_decode"])
+def test_compiled_kernel_refuses_cpu(name):
+    """Kernels default to compiled: off the TPU, a call that does not ask
+    for interpret mode fails loudly instead of interpreting silently."""
+    assert jax.default_backend() == "cpu"
+    f32 = jnp.ones
+    args = {
+        "lstm_cell": (f32((8, 16)), f32((8, 8)), f32((8, 8)), f32((24, 32)),
+                      f32((32,))),
+        "rmsnorm": (f32((8, 128)), f32((128,))),
+        "flash_attention": (f32((1, 64, 2, 32)),) * 3,
+        "ternary_encode": (f32((64,)), jnp.float32(1.0)),
+        "ternary_decode": (jnp.zeros((16,), jnp.uint8), jnp.float32(1.0)),
+    }[name]
+    with pytest.raises(ValueError, match="interpret"):
+        getattr(ops, name)(*args)
