@@ -18,6 +18,8 @@ from typing import Any, Optional, Tuple
 import msgpack
 import numpy as np
 
+from repro import obs
+
 try:  # optional: zstd compresses better/faster, but the stdlib must suffice
     import zstandard
     _CTX = zstandard.ZstdCompressor(level=3)
@@ -70,7 +72,9 @@ def dumps(tree: Any, compress: bool = True,
           codec: Optional[str] = None) -> bytes:
     """Serialize. ``codec`` forces "zstd"/"zlib"; default picks zstd when
     installed, zlib otherwise. The choice is recorded in the header byte."""
-    raw = msgpack.packb(_walk(tree, _pack_leaf), use_bin_type=True)
+    with obs.span("repro.to_host"):
+        packed = _walk(tree, _pack_leaf)
+    raw = msgpack.packb(packed, use_bin_type=True)
     if not compress:
         return b"R" + raw
     codec = codec or DEFAULT_CODEC

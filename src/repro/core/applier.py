@@ -29,6 +29,7 @@ from __future__ import annotations
 
 from typing import Any, List, Optional
 
+from repro import obs
 from repro.core.protocol import ModelBlob, ServerApplier, wire_size
 from repro.core.tasks import GradResult
 
@@ -118,17 +119,20 @@ class RealApplier:
         blobs: List[Any] = []
         if not self.batch:
             p, s = self._params, self._opt_state
-            for r in results:
-                if isinstance(r, GradResult):
-                    p, s = prob.apply_one(p, s, r.payload)
-                else:
-                    p, s = prob.apply_delta(p, s, r.payload, r.weight)
-                blobs.append((p, s))
+            with obs.span("repro.apply"):
+                for r in results:
+                    if isinstance(r, GradResult):
+                        p, s = prob.apply_one(p, s, r.payload)
+                    else:
+                        p, s = prob.apply_delta(p, s, r.payload, r.weight)
+                    blobs.append((p, s))
             self._params, self._opt_state = p, s
         elif isinstance(results[0], GradResult):
-            rows = prob.pack_grad_rows([r.payload for r in results])
-            self._carry, steps = prob.apply_batch_flat(self._carry, rows,
-                                                       donate=True)
+            with obs.span("repro.pack"):
+                rows = prob.pack_grad_rows([r.payload for r in results])
+            with obs.span("repro.apply"):
+                self._carry, steps = prob.apply_batch_flat(self._carry, rows,
+                                                           donate=True)
             for i in range(len(results)):
                 blobs.append(LazyModelBlob(
                     lambda i=i: prob.unflatten_step(steps, i)))
@@ -136,11 +140,12 @@ class RealApplier:
             # LocalSteps deltas: weighted pytree adds, chained eagerly (the
             # delta path is model-transfer-bound, not dispatch-bound); the
             # repack below copies, so the published pytrees stay valid
-            p, s = prob.unflatten_carry(self._carry)
-            for r in results:
-                p, s = prob.apply_delta(p, s, r.payload, r.weight)
-                blobs.append((p, s))
-            self._carry = prob.flat_carry(p, s)
+            with obs.span("repro.apply"):
+                p, s = prob.unflatten_carry(self._carry)
+                for r in results:
+                    p, s = prob.apply_delta(p, s, r.payload, r.weight)
+                    blobs.append((p, s))
+                self._carry = prob.flat_carry(p, s)
         self.version += len(results)
         return blobs
 

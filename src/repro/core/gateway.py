@@ -81,6 +81,7 @@ import time
 from collections import deque
 from typing import Deque, Dict, Optional, Tuple
 
+from repro import obs
 from repro.checkpoint import serialize
 from repro.compile_cache import place_compile_cache
 from repro.core import wsframing
@@ -150,11 +151,14 @@ def _monitor():
 def _make_lock(name: str, *, guard: bool = False):
     """Lock seam: a plain ``threading.Lock`` normally, a ``MonitoredLock``
     under instrumentation. ``guard=True`` marks a dispatch lock no blocking
-    call may run under (LOCK-BLOCK)."""
+    call may run under (LOCK-BLOCK); while ``repro.obs`` is on, its waits
+    and holds are also spans (``obs.TimedLock``)."""
     mon = _monitor()
-    if mon is not None:
-        return mon.make_lock(name, guard=guard)
-    return threading.Lock()
+    lock = mon.make_lock(name, guard=guard) if mon is not None \
+        else threading.Lock()
+    if guard and obs.enabled():
+        return obs.TimedLock(lock)
+    return lock
 
 
 @contextlib.contextmanager
@@ -185,10 +189,10 @@ def _sock_timeout(sock: socket.socket, timeout: Optional[float]):
             pass
 
 
-def _send_frame(sock: socket.socket, msg) -> int:
+def _send_frame(sock: socket.socket, msg) -> None:
     data = encode_message(msg)
-    sock.sendall(_LEN.pack(len(data)) + data)
-    return _LEN.size + len(data)
+    with obs.span("repro.send"):
+        sock.sendall(_LEN.pack(len(data)) + data)
 
 
 def _recv_exact(sock: socket.socket, n: int, *,
@@ -364,8 +368,8 @@ class _TcpChannel:
     def handshake(self) -> bool:
         return True                  # the native dialect has no preamble
 
-    def send(self, msg) -> int:
-        return _send_frame(self.conn, msg)
+    def send(self, msg) -> None:
+        _send_frame(self.conn, msg)
 
     def recv(self):
         """Next protocol message; None = connection over."""
@@ -424,10 +428,10 @@ class _WsChannel:
                 return False
         return True
 
-    def send(self, msg) -> int:
-        frame = self.framer.send_message(encode_message(msg))
-        self.conn.sendall(frame)
-        return len(frame)
+    def send(self, msg) -> None:
+        data = encode_message(msg)
+        with obs.span("repro.send"):
+            self.conn.sendall(self.framer.send_message(data))
 
     def _read_chunk(self) -> Optional[bytes]:
         try:
@@ -1350,13 +1354,14 @@ class GatewayServer:
                 with self._submit_lock:
                     batch, self._submit_pending = self._submit_pending, []
                 if batch:
-                    replies = self.endpoint.submit_batch(
-                        [e[0] for e in batch])
-                    if self._oplog is not None:
-                        sends = list(zip(batch, replies))
-                    else:
-                        for e, reply in zip(batch, replies):
-                            self._send_submit_reply(e, reply)
+                    with obs.span("repro.drain", n=len(batch)):
+                        replies = self.endpoint.submit_batch(
+                            [e[0] for e in batch])
+                        if self._oplog is not None:
+                            sends = list(zip(batch, replies))
+                        else:
+                            for e, reply in zip(batch, replies):
+                                self._send_submit_reply(e, reply)
                     for e in batch:
                         p = self._maybe_snapshot(e[0])
                         if p is not None:
@@ -1381,11 +1386,14 @@ class GatewayServer:
         if channel is None:
             return
         consumer = None
+        seq = -1                 # the request's index on this connection,
+        #                          as the client's transport counts its calls
         try:
             while True:
                 msg = channel.recv()
                 if msg is None:
                     break
+                seq += 1
                 if isinstance(msg, Forward) and \
                         isinstance(msg.inner, SubmitUpdate) and \
                         self.applier is not None:
@@ -1416,9 +1424,11 @@ class GatewayServer:
                     if isinstance(msg, Hello):
                         consumer = msg.consumer
                         self._conns[consumer] = channel
-                    reply = self.endpoint.handle(msg)
-                    if self._oplog is None:
-                        channel.send(reply)
+                    with obs.span("repro.serve", type=type(msg).__name__,
+                                  vid=consumer or "", seq=seq):
+                        reply = self.endpoint.handle(msg)
+                        if self._oplog is None:
+                            channel.send(reply)
                     pending = self._maybe_snapshot(msg)
                     if self.ds.latest_version >= self.n_updates:
                         self.done.set()
@@ -1515,9 +1525,9 @@ class _FramedClientTransport(Transport):
         self.sock = _connect_with_retry(host, port, connect_timeout)
         self.inbox: Deque = deque()
         self.consumer = consumer
-        self.bytes_moved = 0
         self.sent: Dict[str, int] = {}   # request-type histogram (observable:
         #                                  the applier path sends no PublishModel)
+        self._seq = 0                    # calls made, the gateway counts alike
         try:
             self._setup()
             self.call(Hello(consumer))
@@ -1528,7 +1538,7 @@ class _FramedClientTransport(Transport):
     def _setup(self) -> None:
         """Dialect handshake, run once before the Hello."""
 
-    def _send_msg(self, msg) -> int:
+    def _send_msg(self, msg) -> None:
         raise NotImplementedError
 
     def _recv_msg(self):
@@ -1547,15 +1557,17 @@ class _FramedClientTransport(Transport):
     def call(self, msg):
         name = type(msg).__name__
         self.sent[name] = self.sent.get(name, 0) + 1
-        self.bytes_moved += self._send_msg(msg)
-        while True:
-            reply = self._recv_msg()
-            if reply is None:
-                raise ConnectionError("gateway closed the connection")
-            if isinstance(reply, NOTIFICATION_TYPES):
-                self.inbox.append(reply)
-                continue
-            return reply
+        seq, self._seq = self._seq, self._seq + 1
+        with obs.span("repro.call", type=name, vid=self.consumer, seq=seq):
+            self._send_msg(msg)
+            while True:
+                reply = self._recv_msg()
+                if reply is None:
+                    raise ConnectionError("gateway closed the connection")
+                if isinstance(reply, NOTIFICATION_TYPES):
+                    self.inbox.append(reply)
+                    continue
+                return reply
 
     def wait_notification(self, timeout: Optional[float] = None):
         """Block until the server pushes a Wake/VersionReady frame. With a
@@ -1586,8 +1598,8 @@ class SocketTransport(_FramedClientTransport):
 
     dialect = "tcp"
 
-    def _send_msg(self, msg) -> int:
-        return _send_frame(self.sock, msg)
+    def _send_msg(self, msg) -> None:
+        _send_frame(self.sock, msg)
 
     def _recv_msg(self):
         return _recv_frame(self.sock)
@@ -1626,10 +1638,10 @@ class WsClientTransport(_FramedClientTransport):
         if handshake.leftover:
             self._events.extend(self.framer.feed(handshake.leftover))
 
-    def _send_msg(self, msg) -> int:
-        frame = self.framer.send_message(encode_message(msg))
-        self.sock.sendall(frame)
-        return len(frame)
+    def _send_msg(self, msg) -> None:
+        data = encode_message(msg)
+        with obs.span("repro.send"):
+            self.sock.sendall(self.framer.send_message(data))
 
     def _recv_msg(self):
         while True:

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.paper_lstm import CONFIG as LSTM_CONFIG, TrainParams, PAPER_PARAMS
 from repro.data.text import TextTask
 from repro.models import model as M
@@ -113,9 +114,10 @@ class TrainingProblem:
     # ------------------------------------------------------------------ compute
     def map_compute(self, params, version: int, mb_index: int):
         """Returns (grads, loss)."""
-        batch = self.minibatch(version, mb_index)
-        loss, grads = self._grad_fn(params, batch)
-        return grads, float(loss)
+        with obs.span("repro.step"):
+            batch = self.minibatch(version, mb_index)
+            loss, grads = self._grad_fn(params, batch)
+            return grads, float(loss)
 
     def reduce_compute(self, params, opt_state, grads_by_mb: Dict[int, Any]):
         """grads_by_mb: mb_index -> grads. Deterministic order via sort."""

@@ -54,6 +54,7 @@ import dataclasses
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from repro import obs
 from repro.checkpoint import serialize
 from repro.core.aggregation import AggregationPolicy, SyncBSP, make_policy
 from repro.core.dataserver import DataServer
@@ -114,12 +115,14 @@ def encode_message(msg, *, codec: Optional[str] = None) -> bytes:
     """Message -> canonical bytes. Uncompressed by default (protocol messages
     are small and latency-bound); pass codec="zlib"/"zstd" to compress bulky
     payloads (model blobs, dense gradients) through the serialize codecs."""
-    return serialize.dumps(_to_obj(msg), compress=codec is not None,
-                           codec=codec)
+    with obs.span("repro.encode"):
+        return serialize.dumps(_to_obj(msg), compress=codec is not None,
+                               codec=codec)
 
 
 def decode_message(data: bytes):
-    return _from_obj(serialize.loads(data))
+    with obs.span("repro.decode"):
+        return _from_obj(serialize.loads(data))
 
 
 def wire_size(msg, *, codec: Optional[str] = None) -> int:
@@ -579,14 +582,15 @@ class ServerEndpoint:
         base = self.ds.latest_version
         v = base
         admitted: List[Tuple[int, SubmitUpdate]] = []
-        for i, m in enumerate(msgs):
-            if ap.policy.admit(m.result.computed_at, v):
-                admitted.append((i, m))
-                v += 1
-            else:
-                ap.rejected += 1
-                self.qs.nack(m.queue, m.tag, front=True)
-                replies[i] = UpdateRejected(v)
+        with obs.span("repro.admit"):
+            for i, m in enumerate(msgs):
+                if ap.policy.admit(m.result.computed_at, v):
+                    admitted.append((i, m))
+                    v += 1
+                else:
+                    ap.rejected += 1
+                    self.qs.nack(m.queue, m.tag, front=True)
+                    replies[i] = UpdateRejected(v)
         if not admitted:
             return replies
         blob = self.ds.get_model(base)
@@ -613,11 +617,13 @@ class ServerEndpoint:
             blobs.extend(out)
             blob = out[-1]
             pos = end
-        for k, ((i, m), b) in enumerate(zip(admitted, blobs)):
-            self.ds.publish_model(base + k + 1, b, nbytes=ap.nbytes_for(b))
-            self.qs.ack(m.queue, m.tag)
-            ap.applied += 1
-            replies[i] = UpdateCommitted(base + k + 1)
+        with obs.span("repro.publish"):
+            for k, ((i, m), b) in enumerate(zip(admitted, blobs)):
+                self.ds.publish_model(base + k + 1, b,
+                                      nbytes=ap.nbytes_for(b))
+                self.qs.ack(m.queue, m.tag)
+                ap.applied += 1
+                replies[i] = UpdateCommitted(base + k + 1)
         if ap.gc_keep is not None:
             self.ds.gc_models(keep_last=ap.gc_keep)
         return replies
@@ -663,7 +669,8 @@ class ServerEndpoint:
             if blob is not None and hasattr(blob, "materialize"):
                 # a batched real applier publishes lazy blobs; a fetch is
                 # exactly the moment the pytree form is actually needed
-                blob = blob.materialize()
+                with obs.span("repro.materialize"):
+                    blob = blob.materialize()
             return ModelBlob(m.version, blob is not None, blob)
         if isinstance(m, PublishModel):
             return Ok(self.ds.publish_model(m.version, m.blob,
