@@ -17,9 +17,11 @@ Two modes:
   "single-dispatch".
 * ``batch=True`` — the fast path: flat donated ``lax.scan`` chains a whole
   admitted drain in ONE jitted dispatch, and every intermediate version is
-  published as a ``LazyModelBlob`` that unflattens only if somebody actually
-  fetches it (most intermediate versions are GC'd unseen, and eagerly
-  unflattening each one would cost more than the batching saves).
+  published as a ``LazyModelBlob`` that is copied to the host only if
+  somebody actually fetches it (most intermediate versions are GC'd unseen,
+  and eagerly copying each one would cost more than the batching saves).
+  A materialized version is a host (NumPy) pytree made by one device program
+  and ONE device-to-host transfer (``TrainingProblem.unflatten_step``).
 
 Bit-exactness of the two modes — and of any drain split — is the contract
 tests/test_applier.py enforces against the ``sequential_async`` /
@@ -37,12 +39,14 @@ from repro.core.tasks import GradResult
 class LazyModelBlob:
     """A published model version materialized on first access.
 
-    The batched applier publishes B intermediate versions per drain as views
-    into the scan's stacked per-step outputs; ``materialize()`` slices and
-    unflattens exactly once, caching the pytree. ``ServerEndpoint`` serves
-    ``FetchModel`` with the materialized value and ``DataServer.snapshot``
-    solidifies stored blobs, so laziness never crosses the wire or lands in
-    a checkpoint."""
+    The batched applier publishes B intermediate versions per drain as rows
+    of the scan's stacked per-step outputs, left on the device;
+    ``materialize()`` copies its row to the host exactly once (one transfer)
+    and caches the host pytree of read-only NumPy arrays, dropping its hold
+    on the drain's device outputs. ``ServerEndpoint`` serves ``FetchModel``
+    with the materialized value and ``DataServer.snapshot`` solidifies
+    stored blobs, so laziness never crosses the wire or lands in a
+    checkpoint."""
 
     __slots__ = ("_thunk", "_value")
 
@@ -68,6 +72,9 @@ class RealApplier:
         self.problem = problem
         self.batch = bool(batch) and problem.supports_flat_apply
         self.version = 0
+        #: lazily-published versions copied to the host, one per
+        #: materialized version (a repeated fetch of one copies nothing)
+        self.host_copies = 0
         self._nbytes: Optional[int] = None
         if self.batch:
             self._carry = problem.flat_carry(problem.params0,
@@ -107,6 +114,10 @@ class RealApplier:
             self._params, self._opt_state = p, s
         self.version = version
 
+    def _host_copy(self, steps, i: int):
+        self.host_copies += 1
+        return self.problem.unflatten_step(steps, i)
+
     def _advance(self, results: List[Any], base_version: int) -> List[Any]:
         """Apply a homogeneous admitted run (the endpoint segments drains by
         result type) and return the successive post-update blobs."""
@@ -135,7 +146,7 @@ class RealApplier:
                                                            donate=True)
             for i in range(len(results)):
                 blobs.append(LazyModelBlob(
-                    lambda i=i: prob.unflatten_step(steps, i)))
+                    lambda i=i: self._host_copy(steps, i)))
         else:
             # LocalSteps deltas: weighted pytree adds, chained eagerly (the
             # delta path is model-transfer-bound, not dispatch-bound); the

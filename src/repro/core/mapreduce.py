@@ -252,27 +252,74 @@ class TrainingProblem:
     def _unflatten_fn(self):
         # unflatten is pure data movement (split/reshape), so jitting cannot
         # change bits — and it folds the dozens of eager slice dispatches
-        # into ONE, which is what makes materializing a lazily-published
-        # version (FetchModel, measure, snapshot) cheap
+        # into ONE (the LocalSteps delta path unflattens the hot carry)
         return jax.jit(self._unflatten_carry_impl)
 
     def unflatten_carry(self, carry):
         """Inverse of ``flat_carry``: (params, opt_state) pytrees."""
         return self._unflatten_fn(carry)
 
+    def _carry_parts(self, carry):
+        """The arrays of a flat carry in one fixed order: the flat params,
+        each flat optimizer-state subtree, then each scalar."""
+        fp, vecs, scalars = carry
+        _, _, _, _, tree_keys, scalar_keys = self._flat_spec
+        return ([fp] + [vecs[k] for k in tree_keys]
+                + [scalars[k] for k in scalar_keys])
+
     @functools.cached_property
-    def _unflatten_step_fn(self):
-        # fused slice+unflatten, one dispatch; ``i`` traces as a dynamic
-        # scalar so one compilation serves every step index (retraced only
-        # per distinct leading batch length)
-        return jax.jit(lambda steps, i: self._unflatten_carry_impl(
-            jax.tree.map(lambda a: a[i], steps)))
+    def _row_layout(self):
+        """((dtype, shape) of each carry part, the unsigned word of a packed
+        row). The word is as wide as the narrowest part, so every part
+        bit-casts into whole words: uint32 for float32 weights and an int32
+        step."""
+        parts = self._carry_parts(jax.eval_shape(
+            self.flat_carry, self.params0, self.opt_state0))
+        word = np.dtype(f"uint{8 * min(p.dtype.itemsize for p in parts)}")
+        return tuple((np.dtype(p.dtype), p.shape) for p in parts), word
+
+    def _pack_row(self, steps, i):
+        word = self._row_layout[1]
+        return jnp.concatenate(
+            [jax.lax.bitcast_convert_type(a[i], word).ravel()
+             for a in self._carry_parts(steps)])
+
+    @functools.cached_property
+    def _pack_step_fn(self):
+        # one program slices row ``i`` of every part and bit-casts it into
+        # one buffer of words: a bit-cast is no float operation, so no
+        # integer's bits pass through float arithmetic and no denormal is
+        # flushed. ``i`` traces as a dynamic scalar, so one compilation
+        # serves every step index (retraced only per drain length)
+        return jax.jit(lambda steps, i: self._pack_row(steps, i))
+
+    def _unpack_row(self, words: np.ndarray):
+        """(params, opt_state) as read-only NumPy views of a packed row:
+        split, bit-cast back and reshaped, so every bit is the device's."""
+        treedef, shapes, sizes, _, tree_keys, scalar_keys = self._flat_spec
+        layout, word = self._row_layout
+        words.flags.writeable = False
+        counts = [int(np.prod(shape)) * dt.itemsize // word.itemsize
+                  for dt, shape in layout]
+        parts = [seg.view(dt).reshape(shape) for seg, (dt, shape) in
+                 zip(np.split(words, np.cumsum(counts)[:-1]), layout)]
+        leaf_ends = np.cumsum(sizes)[:-1]
+
+        def tree(vec):
+            return jax.tree.unflatten(treedef, [
+                x.reshape(s) for x, s in zip(np.split(vec, leaf_ends), shapes)])
+
+        state = {k: tree(v) for k, v in zip(tree_keys, parts[1:])}
+        state.update(zip(scalar_keys, parts[1 + len(tree_keys):]))
+        return tree(parts[0]), state
 
     def unflatten_step(self, steps, i: int):
-        """(params, opt_state) at row ``i`` of a scan's stacked step outputs
-        — eager per-leaf indexing costs ~200us/leaf on this box, which is
-        what lazily-published versions must NOT pay per materialize."""
-        return self._unflatten_step_fn(steps, i)
+        """(params, opt_state) at row ``i`` of a scan's stacked step outputs,
+        as host NumPy arrays: one device program packs the row into one
+        buffer, ONE device-to-host transfer brings it over, and the pytree
+        is read-only views of that buffer (what a lazily-published version
+        becomes when it is fetched, measured or snapshotted)."""
+        return self._unpack_row(np.asarray(self._pack_step_fn(steps, i)))
 
     def _flat_step(self, carry, g):
         fp, vecs, scalars = carry
@@ -305,8 +352,9 @@ class TrainingProblem:
 
     def apply_batch(self, params, opt_state, grads_seq):
         """Pytree-level batched apply: one scan dispatch over a sequence of
-        gradient pytrees. Returns the list of per-step (params, opt_state) —
-        bit-identical to folding ``apply_one`` over ``grads_seq``."""
+        gradient pytrees. Returns the list of per-step (params, opt_state),
+        as host arrays, bit-identical to folding ``apply_one`` over
+        ``grads_seq``."""
         if not grads_seq:
             return []
         rows = jnp.asarray(self.pack_grad_rows(grads_seq))

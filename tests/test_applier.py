@@ -13,6 +13,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -30,6 +31,7 @@ from repro.core.simulator import (CostModel, Simulator, SyntheticProblem,
                                   VolunteerSpec)
 from repro.core.tasks import DeltaResult, GradResult, INITIAL_QUEUE
 from repro.data.text import synthetic_corpus
+from repro.optim import rmsprop
 
 N = 12  # updates per staged chain — enough for multi-segment drains
 
@@ -349,16 +351,90 @@ def test_pack_grad_rows_matches_per_row_pack(problem, grads):
     assert np.array_equal(rows, expect)
 
 
-def test_unflatten_step_matches_eager_slice(problem, grads):
-    carry = problem.flat_carry(problem.params0, problem.opt_state0)
-    rows = problem.pack_grad_rows(grads[:4])
-    _, steps = problem.apply_batch_flat(carry, rows, donate=False)
+def _eager_row(problem, steps, i):
     fp_s, vec_s, scal_s = steps
-    for i in (0, 3):
-        eager = problem.unflatten_carry(
-            (fp_s[i], {k: v[i] for k, v in vec_s.items()},
-             {k: v[i] for k, v in scal_s.items()}))
-        assert bit_eq(problem.unflatten_step(steps, i), eager)
+    return problem.unflatten_carry(
+        (fp_s[i], {k: v[i] for k, v in vec_s.items()},
+         {k: v[i] for k, v in scal_s.items()}))
+
+
+@pytest.mark.parametrize("n,i", [(n, i) for n in (1, 3, 8) for i in range(n)])
+def test_unflatten_step_matches_eager_slice(problem, grads, n, i):
+    """A version copied to the host in one transfer: read-only NumPy leaves,
+    bit-equal to the device's own slice of the row, the step still int32."""
+    carry = problem.flat_carry(problem.params0, problem.opt_state0)
+    rows = problem.pack_grad_rows(grads[:n])
+    _, steps = problem.apply_batch_flat(carry, rows, donate=False)
+    got = problem.unflatten_step(steps, i)
+    assert bit_eq(got, _eager_row(problem, steps, i))
+    for leaf in jax.tree.leaves(got):
+        assert type(leaf) is np.ndarray and not leaf.flags.writeable
+    step = got[1]["step"]
+    assert step.dtype == np.int32 and int(step) == i + 1
+
+
+def test_unflatten_step_packs_narrower_words(problem, grads):
+    """bfloat16 weights and state beside the int32 step pack into 16-bit
+    words, and come back bit for bit."""
+    opt = rmsprop(0.1, state_dtype=jnp.bfloat16)
+    p16 = jax.tree.map(lambda x: x.astype(jnp.bfloat16), problem.params0)
+    narrow = dataclasses.replace(problem, optimizer=opt, params0=p16,
+                                 opt_state0=opt.init(p16))
+    assert narrow._row_layout[1] == np.uint16
+    carry = narrow.flat_carry(narrow.params0, narrow.opt_state0)
+    _, steps = narrow.apply_batch_flat(carry, narrow.pack_grad_rows(grads[:3]),
+                                       donate=False)
+    for i in range(3):
+        got = narrow.unflatten_step(steps, i)
+        assert bit_eq(got, _eager_row(narrow, steps, i))
+        assert jax.tree.leaves(got[0])[0].dtype == jnp.bfloat16
+
+
+def test_host_copies_count_distinct_versions_fetched(problem, grads):
+    endpoint, qs, ds, ap = fresh_endpoint(problem, batch=True)
+    submit(endpoint, qs, grad_results(grads[:4]), split=[4])
+    backend = ap.backend
+    # measuring the first publish's wire size copied version 1
+    assert backend.host_copies == 1
+    # version 0 was published eager and needs no copy
+    for v, copies in [(3, 2), (3, 2), (2, 3), (1, 3), (0, 3), (2, 3),
+                      (4, 4)]:
+        endpoint.handle(FetchModel(v))
+        assert backend.host_copies == copies
+
+
+def test_warmed_drain_length_fetches_without_a_compile(problem, grads):
+    """Warming ``unflatten_step`` at drain length n, as the benchmark's
+    set-up does, leaves nothing to compile when a version from a drain of
+    that length is first fetched; a length left unwarmed compiles then."""
+    fresh = dataclasses.replace(problem)        # jit caches of its own
+    rows = np.zeros((3, fresh.pack_grads(fresh.params0).size), np.float32)
+    for n, warm_fetch in [(3, True), (2, False)]:
+        carry = fresh.flat_carry(fresh.params0, fresh.opt_state0)
+        _, steps = fresh.apply_batch_flat(carry, rows[:n], donate=True)
+        if warm_fetch:
+            jax.block_until_ready(fresh.unflatten_step(steps, n - 1))
+    endpoint, qs, ds, ap = fresh_endpoint(fresh, batch=True)
+    compiles = []
+
+    def listen(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        submit(endpoint, qs, grad_results(grads[:3]), split=[3])
+        endpoint.handle(FetchModel(3))
+        assert compiles == []
+        submit(endpoint, qs, [GradResult(version=3 + i, mb_index=0,
+                                         payload=g, computed_at=3 + i)
+                              for i, g in enumerate(grads[3:5])], split=[2])
+        assert compiles == []
+        endpoint.handle(FetchModel(5))
+        assert compiles
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert ap.backend.host_copies == 3        # versions 1 (measured), 3, 5
 
 
 def test_flat_carry_round_trips(problem):

@@ -118,3 +118,15 @@ def test_paper_applier_scan_compiles(one_chip, paper_problem):
     rows = _spec(one_chip, (16, carry[0].shape[0]))
     jax.jit(p.apply_batch_flat).lower(_on_chip(carry, one_chip),
                                       rows).compile()
+
+
+def test_paper_version_pack_compiles(one_chip, paper_problem):
+    """The program that packs row i of a drain of 16 into the one buffer a
+    fetched version is copied to the host in."""
+    p = paper_problem
+    carry = jax.eval_shape(p.flat_carry, p.params0, p.opt_state0)
+    steps = jax.tree.map(lambda x: _spec(one_chip, (16,) + x.shape, x.dtype),
+                         carry)
+    out = p._pack_step_fn.lower(steps, _spec(one_chip, (), jnp.int32))
+    assert out.out_info.shape == (2 * carry[0].shape[0] + 1,)
+    out.compile()
