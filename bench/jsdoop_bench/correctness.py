@@ -15,7 +15,16 @@ Two parts, both read from what the timed path produced:
 * Commits of the window, a sample drawn from the seed plus the final
   version, followed alike: the parameters' one-step change is compared.
 
-Every version the reference starts from is fetched back over the wire.
+Every version the reference starts from is fetched back over the wire
+into a ``Versions`` store on disk: versions 1 to 3 during set-up, once
+version 3 is published and before the window opens (the reference starts
+from version 0 as the seed makes it); the window's after it has closed,
+from the commits whose versions ``c - 1``, ``c`` and base the server still
+holds then. The check keeps its own versions, and reads only those of the
+commit it follows, so its host memory does not grow with the model's
+retention or with ``checked_commits``. The exact check ``unchecked``
+counts the sampled commits, and the final version, that the program's
+retention left out.
 
 Norms are compared by leaf: the gap between the program's norm and the
 reference's, over the reference's norm of that leaf or of the median leaf,
@@ -23,13 +32,16 @@ whichever is larger. Leaves whose reference gradient (in the window: whose
 reference step) is under a thousandth of the median leaf's are left out of
 the changes. Exact checks of
 the commit log (every version published once, every ticket committed
-once, no update admitted beyond the staleness bound, no volunteer stuck)
-have the limit 0.
+once, no update admitted beyond the staleness bound, no volunteer stuck,
+every sampled commit checkable) have the limit 0.
 """
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+import tempfile
+from collections.abc import Mapping
+from pathlib import Path
+from typing import AbstractSet, Any, Dict, Iterable, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -39,8 +51,52 @@ import numpy as np
 NEGLIGIBLE = 1e-3
 #: what the numbers of the first part follow
 FIRST = 3
+#: the versions they are followed from, fetched during set-up: commit c <=
+#: FIRST was computed at a base before c, and version 0 is the seed's
+FIRST_VERSIONS = tuple(range(1, FIRST + 1))
 NUMBERS = ("loss_gap", "grad1_gap", "change_gap", "step_gap")
-EXACT = ("version_gaps", "ticket_repeats", "over_stale", "stuck")
+EXACT = ("version_gaps", "ticket_repeats", "over_stale", "stuck",
+         "unchecked")
+
+
+class Versions(Mapping):
+    """Published versions fetched for the check, version -> host pytree,
+    kept on disk leaf by leaf as each arrives, in a temporary directory
+    that ``close()`` removes. Reading a version loads it anew, so the
+    check holds only the versions it is reading."""
+
+    def __init__(self):
+        self._dir = tempfile.TemporaryDirectory(prefix="bench-versions-")
+        self._trees: Dict[int, Any] = {}         # version -> tree structure
+
+    def put(self, version: int, tree) -> None:
+        leaves, treedef = jax.tree.flatten(tree)
+        for i, leaf in enumerate(leaves):
+            np.save(self._path(version, i), np.asarray(leaf),
+                    allow_pickle=False)
+        self._trees[version] = treedef
+
+    def _path(self, version: int, leaf: int) -> Path:
+        return Path(self._dir.name) / f"{version}.{leaf}.npy"
+
+    def __getitem__(self, version: int):
+        treedef = self._trees[version]
+        return jax.tree.unflatten(treedef, [
+            np.load(self._path(version, i), allow_pickle=False)
+            for i in range(treedef.num_leaves)])
+
+    def __contains__(self, version) -> bool:
+        return version in self._trees
+
+    def __iter__(self):
+        return iter(sorted(self._trees))
+
+    def __len__(self) -> int:
+        return len(self._trees)
+
+    def close(self) -> None:
+        self._trees.clear()
+        self._dir.cleanup()
 
 
 def commit_log(tickets) -> Dict[int, Any]:
@@ -48,22 +104,43 @@ def commit_log(tickets) -> Dict[int, Any]:
     return {t.committed: t for t in tickets if t.committed > 0}
 
 
-def sample_commits(record, sample_seed: int, n: int) -> List[int]:
+def _needs(commits: Dict[int, Any], c: int) -> set:
+    """The fetched versions commit ``c`` is followed from: ``c - 1``, ``c``
+    and its volunteer's base, but for version 0, which is the seed's."""
+    return {c - 1, c, commits[c].base} - {0}
+
+
+def sample_commits(record, sample_seed: int, n: int, held: AbstractSet[int]
+                   ) -> Tuple[List[int], int]:
     """``n`` commits whose reply reached their volunteer in the window,
-    drawn from the seed, and the final version."""
+    drawn from the seed, and the final version. A commit can be drawn only
+    where ``held``, the versions the server still holds once the window
+    has closed, holds the commit's versions; the final version's too.
+    Returns the sample and its shortfall: how many of the final version
+    and of the ``n`` (or of the window's commits, where it made fewer)
+    could not be drawn for want of their versions."""
+    commits = commit_log(record.tickets)
+
+    def checkable(c: int) -> bool:
+        return _needs(commits, c) <= held
+
     inside = sorted(t.committed for t in record.tickets
                     if t.committed > 0 and record.in_window(t.t_reply))
+    population = [c for c in inside if checkable(c)]
     rng = np.random.default_rng(sample_seed)
-    picked = rng.choice(inside, size=min(n, len(inside)), replace=False) \
-        if inside else []
+    picked = rng.choice(population, size=min(n, len(population)),
+                        replace=False) if population else []
     final = max(t.committed for t in record.tickets)
-    return sorted({int(c) for c in picked} | {final})
+    shortfall = min(n, len(inside)) - len(picked) + (not checkable(final))
+    return sorted({int(c) for c in picked}
+                  | ({final} if checkable(final) else set())), shortfall
 
 
 def versions_needed(commits: Dict[int, Any], sample: Iterable[int]) -> List[int]:
-    want = set(range(FIRST + 1))
+    """The fetched versions the window's sample is followed from."""
+    want = set()
     for c in sample:
-        want |= {c - 1, c, commits[c].base}
+        want |= _needs(commits, c)
     return sorted(want)
 
 
@@ -144,32 +221,35 @@ def readings(reference, record, commits, sample, fetched, params0, *,
     place: the control and the planted faults read so."""
     kind = record.kind
     rho = record.config["rho"]
-    fetched = dict(fetched)
-    fetched[0] = reference.zero_state(params0)
+    zero = reference.zero_state(params0)
+
+    def version(v):
+        return zero if v == 0 else fetched[v]
 
     def follow(c):
-        """(reference's state after c, program's state after c, reference's
-        loss, program's loss), both from the fetched versions."""
+        """(program's parameters before c, reference's state after c,
+        program's state after c, reference's loss, program's loss), all from
+        the fetched versions, each read once."""
         t = commits[c]
-        before, at_base = fetched[c - 1], fetched[t.base]
+        before = version(c - 1)
+        at_base = before if t.base == c - 1 else version(t.base)
         loss_r, contrib = _update(reference, kind, t.task, at_base,
                                   "float32", None)
         want = _apply(reference, kind, before, contrib, weight)
         if stand_in is None:
-            return want, fetched[c], loss_r, t.loss
+            return before[0], want, version(c), loss_r, t.loss
         loss_p, alt = _update(reference, kind, t.task, at_base, *stand_in)
-        return want, _apply(reference, kind, before, alt, weight), loss_r, \
-            loss_p
+        return before[0], want, _apply(reference, kind, before, alt, weight), \
+            loss_r, loss_p
 
     loss_gap = 0.0
     ref_change = prog_change = None
     for c in range(1, FIRST + 1):
-        want, got, loss_r, loss_p = follow(c)
+        before, want, got, loss_r, loss_p = follow(c)
         loss_gap = max(loss_gap, abs(loss_p - loss_r) / abs(loss_r))
         if c == 1:
             g_ref = grad_norms(want[1]["ms"], rho)
             grad1_gap = worst_gap(grad_norms(got[1]["ms"], rho), g_ref)
-        before = fetched[c - 1][0]
         steps = (_params_change(want[0], before), _params_change(got[0], before))
         if ref_change is None:
             ref_change, prog_change = steps
@@ -179,8 +259,7 @@ def readings(reference, record, commits, sample, fetched, params0, *,
     change_gap = worst_gap(_norms(prog_change), _norms(ref_change), kept(g_ref))
     step_gap = 0.0
     for c in sample:
-        want, got, _, _ = follow(c)
-        before = fetched[c - 1][0]
+        before, want, got, _, _ = follow(c)
         ref_step = change_norms(want[0], before)
         step_gap = max(step_gap, worst_gap(change_norms(got[0], before),
                                            ref_step, kept(ref_step)))
@@ -188,10 +267,12 @@ def readings(reference, record, commits, sample, fetched, params0, *,
             "change_gap": change_gap, "step_gap": step_gap}
 
 
-def exact(record, *, staleness: Optional[int], stuck: int) -> Dict[str, int]:
+def exact(record, *, staleness: Optional[int], stuck: int,
+          unchecked: int) -> Dict[str, int]:
     """The commit log's exact checks: versions published once each and
     without a gap, tickets committed once each, admission within the
-    staleness bound, and every volunteer back when the window closed."""
+    staleness bound, every volunteer back when the window closed, and
+    ``unchecked``, the sample's shortfall (``sample_commits``)."""
     versions = [t.committed for t in record.tickets if t.committed > 0]
     top = max(versions, default=0)
     gaps = (top - len(set(versions))) + (len(versions) - len(set(versions)))
@@ -201,7 +282,7 @@ def exact(record, *, staleness: Optional[int], stuck: int) -> Dict[str, int]:
         over = sum(t.committed - 1 - t.base > staleness
                    for t in record.tickets if t.committed > 0)
     return {"version_gaps": gaps, "ticket_repeats": len(tasks) - len(set(tasks)),
-            "over_stale": over, "stuck": stuck}
+            "over_stale": over, "stuck": stuck, "unchecked": unchecked}
 
 
 def judge(values: Dict[str, Any], limits: Dict[str, float]
@@ -222,12 +303,15 @@ def judge(values: Dict[str, Any], limits: Dict[str, float]
 
 def check(reference, record, commits, sample, fetched, params0, *,
           staleness: Optional[int], weight: float, stuck: int,
-          limits: Dict[str, float]) -> Dict[str, Dict[str, Any]]:
+          limits: Dict[str, float], unchecked: int
+          ) -> Dict[str, Dict[str, Any]]:
     """The exact checks and the four numbers of the program's run, each
-    judged against its limit."""
+    judged against its limit. Without the first commits or their versions
+    (the program no longer held them when set-up fetched them) the numbers
+    are infinite."""
     values: Dict[str, Any] = dict(exact(record, staleness=staleness,
-                                        stuck=stuck))
-    if all(c in commits for c in range(1, FIRST + 1)):
+                                        stuck=stuck, unchecked=unchecked))
+    if all(c in commits and c in fetched for c in range(1, FIRST + 1)):
         values.update(readings(reference, record, commits, sample, fetched,
                                params0, weight=weight))
     else:

@@ -13,7 +13,9 @@ from __future__ import annotations
 
 import gc
 import math
+import resource
 import shutil
+import socket
 import sys
 import tempfile
 import threading
@@ -28,11 +30,11 @@ from jsdoop_bench import correctness, trace as tracemod
 from jsdoop_bench.inputs import Seeds, corpus
 from jsdoop_bench.spec import ROOT, Cell, load_family, load_reader
 
-#: device memory the run may fill with published versions before the
-#: schedule of tickets runs out (the served path keeps every version)
-RETAIN_BUDGET_BYTES = 12 * 2**30
-#: and at most this many tickets, whose set-up the gateway pays at start
-MAX_TICKETS = 50_000
+#: tickets every run schedules, whatever the model: the count every cell
+#: was measured with, whose set-up the gateway pays at start. At the
+#: fastest rate measured (ws32 on a TPU v5e, 147 updates/s) it outlasts
+#: warm-up and a 51 s window more than twice
+TICKETS = 20_163
 #: fixed, inside the checkout: the cache's path is part of its key
 CACHE_DIR = ROOT / ".bench_jax_cache"
 WARMUP_TIMEOUT_S = 600.0
@@ -217,11 +219,72 @@ def enable_compile_cache(jax, path=CACHE_DIR) -> None:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
-def ticket_cap(model_bytes: int) -> int:
-    """Tickets to schedule: what fits in ``RETAIN_BUDGET_BYTES`` when every
-    published version stays on the device, counted twice for the copy a
-    fetch materializes, up to ``MAX_TICKETS``. Running out fails the run."""
-    return min(MAX_TICKETS, RETAIN_BUDGET_BYTES // (2 * model_bytes))
+def schedule(per_ticket: int, mini_batches_per_version: int
+             ) -> Tuple[int, int]:
+    """(tickets, versions) of a run's schedule. Running out of tickets
+    fails the run. The schedule assumes nothing of how many versions the
+    program keeps: bounding the memory they take is the program's, and one
+    that holds every version of a large model runs out of memory, which
+    fails the run with the device's and the host's peak memory on standard
+    error."""
+    return TICKETS, math.ceil(TICKETS * per_ticket / mini_batches_per_version)
+
+
+def held_versions(ds) -> set:
+    """The versions the program's store still holds, read in-process
+    without fetching or materializing one."""
+    return {v for v in range(ds.latest_version + 1)
+            if ds.get_model(v) is not None}
+
+
+def fetch_versions(port: int, wanted, versions: correctness.Versions
+                   ) -> List[int]:
+    """Fetch ``wanted`` over the wire into ``versions``, as a client of the
+    gateway on ``port``; returns those the gateway no longer holds."""
+    from repro.core.gateway import SocketTransport
+    from repro.core.protocol import FetchModel
+    missing = []
+    checker = SocketTransport("127.0.0.1", port, "checker")
+    try:
+        for v in wanted:
+            reply = checker.call(FetchModel(v))
+            if reply.present:
+                versions.put(v, tuple(reply.blob))
+            else:
+                missing.append(v)
+    finally:
+        checker.close()
+    return missing
+
+
+def stop_server(server, serving: threading.Thread) -> None:
+    """Close the gateway and wait for its accept loop to end, so that no
+    thread holds the server, and the versions its store keeps, once the
+    harness lets go of it. ``GatewayServer.close`` closes the listening
+    socket, which does not wake a thread blocked in ``accept``; shutting
+    the socket down first does. Remove the shutdown, and the reach into
+    the server's private ``_sock``, once ``GatewayServer.close`` shuts its
+    socket down itself."""
+    try:
+        server._sock.shutdown(socket.SHUT_RDWR)
+    except OSError:
+        pass
+    server.close()
+    serving.join(JOIN_TIMEOUT_S)
+
+
+def _peak_rss_bytes() -> int:
+    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+
+
+def _log_memory(device) -> Dict[str, int]:
+    """Log the device's memory and the process's peak host RSS; returns the
+    device's memory stats."""
+    stats = device.memory_stats() or {}
+    _log(f"device memory: peak_bytes_in_use {stats.get('peak_bytes_in_use')}, "
+         f"bytes_in_use {stats.get('bytes_in_use')}; host peak RSS "
+         f"{_peak_rss_bytes()} B")
+    return stats
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
@@ -244,7 +307,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     from repro.core.aggregation import LocalSteps, make_policy
     from repro.core.gateway import GatewayServer, SocketTransport, \
         WsClientTransport
-    from repro.core.protocol import (FetchModel, LocalWork, MapWork, NoTask,
+    from repro.core.protocol import (LocalWork, MapWork, NoTask,
                                      VolunteerSession)
 
     cfg, mix = cell.config, cell.traffic
@@ -261,8 +324,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     n_vol = int(mix["volunteers"])
     transport_cls = {"tcp": SocketTransport, "ws": WsClientTransport}[mix["dialect"]]
     model_bytes = 2 * 4 * ref.param_count(cfg)          # params + RMSprop ms
-    n_mb = cfg["mini_batches_per_version"]
-    n_versions = math.ceil(ticket_cap(model_bytes) * per_ticket / n_mb)
+    _, n_versions = schedule(per_ticket, cfg["mini_batches_per_version"])
 
     text = corpus(cfg["corpus_chars"], seeds.text)
     params0 = jax.jit(lambda k: ref.init_params(cfg, k))(
@@ -285,7 +347,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     applier = server.applier
     if trace:
         _annotate_server(server, jax)
-    server.start()
+    serving = server.start()
 
     tickets: List[Ticket] = []
     done_by: Dict[str, int] = {}
@@ -342,6 +404,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
             errors.append(e)
             stop.set()
 
+    # the check's own copies of what it follows, on disk
+    versions = correctness.Versions()
+    first_missing: Optional[List[int]] = None      # None: not fetched yet
     threads = [threading.Thread(target=volunteer, args=(f"vol{i}",),
                                 daemon=True) for i in range(n_vol)]
     for th in threads:
@@ -350,10 +415,21 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     while not errors and (len(done_by) < n_vol
                           or min(done_by.values()) < 2
                           or sum(t.committed > 0 for t in tickets)
-                          < mix["warmup_commits"]):
+                          < mix["warmup_commits"]
+                          or first_missing is None):
         if time.perf_counter() > deadline:
             errors.append(RunFailed("warm-up did not finish"))
             break
+        if first_missing is None and \
+                server.ds.latest_version >= correctness.FIRST:
+            # as soon as they are published, before the program can drop
+            # them; outside the window, so no metric sees it
+            try:
+                first_missing = fetch_versions(
+                    server.port, correctness.FIRST_VERSIONS, versions)
+            except Exception as e:          # re-raised once all have stopped
+                errors.append(e)
+            continue
         time.sleep(0.005)
 
     # everything set-up made lives on through the window: left out of the
@@ -381,7 +457,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         th.join(JOIN_TIMEOUT_S)
     stuck = sum(th.is_alive() for th in threads)
     if errors:
-        server.close()
+        _log_memory(devices[0])
+        stop_server(server, serving)
+        versions.close()
         raise errors[0]
     compiles = meter.between(t0, t1)
     if logdir is not None:
@@ -393,16 +471,17 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         finally:
             shutil.rmtree(logdir, ignore_errors=True)
 
-    stats = devices[0].memory_stats() or {}
-    latest = server.ds.latest_version
+    held = held_versions(server.ds)
     _log(f"setup {setup_s:.3f} s (compile {meter.seconds:.3f} s, persistent "
          f"cache hits {meter.hits}); window {seconds} s")
     _log(f"compiles inside the window: {compiles}")
-    _log(f"device memory: peak_bytes_in_use {stats.get('peak_bytes_in_use')}, "
-         f"bytes_in_use {stats.get('bytes_in_use')}")
-    _log(f"retained: {latest + 1} published versions x {model_bytes} B = "
-         f"{(latest + 1) * model_bytes} B of parameters and RMSprop state "
-         f"(every version stays on the device)")
+    stats = _log_memory(devices[0])
+    _log(f"held when the window closed: {len(held)} of "
+         f"{server.ds.latest_version + 1} published versions x {model_bytes} "
+         f"B = {len(held) * model_bytes} B of parameters and RMSprop state")
+    if first_missing:
+        _log(f"versions set-up could not fetch (no longer held): "
+             f"{first_missing}")
     _log(pauses.summary())
     _log("commit_p95_ms by fifths of the window: " + " ".join(
         f"{v:.1f}" for v in _p95_by_part(tickets, t0, t1, 5)))
@@ -418,33 +497,36 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     # -- the check, once the window has closed ------------------------------
     commits = correctness.commit_log(record.tickets)
-    sample = correctness.sample_commits(record, seeds.sample,
-                                        mix["checked_commits"])
-    wanted = correctness.versions_needed(commits, sample)
-    checker = SocketTransport("127.0.0.1", server.port, "checker")
+    sample, unchecked = correctness.sample_commits(
+        record, seeds.sample, mix["checked_commits"], held)
     try:
-        fetched = {}
-        for v in wanted:
-            reply = checker.call(FetchModel(v))
-            if not reply.present:
-                raise RunFailed(f"published version {v} is not fetchable")
-            fetched[v] = jax.tree.map(np.asarray, tuple(reply.blob))
+        wanted = [v for v in correctness.versions_needed(commits, sample)
+                  if v not in versions]
+        try:
+            missing = fetch_versions(server.port, wanted, versions)
+        finally:
+            stop_server(server, serving)
+        if missing:
+            raise RunFailed(f"published version {missing[0]} is not "
+                            f"fetchable")
+        del server, applier, problem, params0
+        gc.collect()
+        reference = ref.Reference(cfg, text, seeds.schedule, n_versions)
+        weight = getattr(policy, "weight", 1.0)
+        checks = correctness.check(
+            reference, record, commits, sample, versions, host_params0,
+            staleness=getattr(policy, "staleness", None), weight=weight,
+            stuck=stuck, limits=cell.limits, unchecked=unchecked)
+        extra = {f"{p}/{f}": correctness.judge(correctness.readings(
+            reference, record, commits, sample, versions, host_params0,
+            weight=weight, stand_in=(p, f)), cell.limits)
+            for p, f in stand_ins}
+        if look is not None:
+            look(reference, record, commits, sample, versions, host_params0,
+                 weight)
     finally:
-        checker.close()
-    server.close()
-    del server, applier, problem, params0
-    gc.collect()
-    reference = ref.Reference(cfg, text, seeds.schedule, n_versions)
-    weight = getattr(policy, "weight", 1.0)
-    checks = correctness.check(reference, record, commits, sample, fetched,
-                               host_params0,
-                               staleness=getattr(policy, "staleness", None),
-                               weight=weight, stuck=stuck, limits=cell.limits)
-    extra = {f"{p}/{f}": correctness.judge(correctness.readings(
-        reference, record, commits, sample, fetched, host_params0,
-        weight=weight, stand_in=(p, f)), cell.limits) for p, f in stand_ins}
-    if look is not None:
-        look(reference, record, commits, sample, fetched, host_params0, weight)
+        versions.close()
+    _log(f"host peak RSS after the check: {_peak_rss_bytes()} B")
 
     metric_specs = cell.per_layer if trace else cell.end_to_end
     metrics = {}

@@ -36,14 +36,14 @@ def small_cell(name: str = "paper-lstm.ws32", *, hidden: int = 8,
 
 
 def run_small(cell: spec.Cell, *, seed: int = 2**31 + 11, seconds: float = 1.0,
-              tamper=None):
+              tamper=None, look=None):
     """One run of ``cell`` on the CPU (the harness's device check is the
     only part left out)."""
     import jax
     from jsdoop_bench import driver
     return driver.run_cell(cell, seed, seconds, False,
                            t_start=time.perf_counter(), devices=jax.devices(),
-                           peaks=None, tamper=tamper)
+                           peaks=None, tamper=tamper, look=look)
 
 
 def sequential_log(cfg, n: int, seed: int = 123):

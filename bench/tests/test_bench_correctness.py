@@ -3,6 +3,7 @@ answers, and failing on a perturbed model, on the control one precision
 below float32 at ``highest`` (three bfloat16 passes per product), on one
 bfloat16 pass, and on planted faults."""
 import json
+import pathlib
 
 import jax
 import pytest
@@ -57,28 +58,77 @@ def test_control_and_faults_fail(served, stand_in):
 
 def test_exact_checks_count_what_went_wrong(served):
     reference, record, commits, fetched, params0 = served
-    ok = correctness.exact(record, staleness=2, stuck=0)
+    ok = correctness.exact(record, staleness=2, stuck=0, unchecked=0)
     assert ok == {"version_gaps": 0, "ticket_repeats": 0, "over_stale": 0,
-                  "stuck": 0}
+                  "stuck": 0, "unchecked": 0}
     broken = list(record.tickets)
     broken[3] = Ticket("v", 4.0, 4.5, broken[2].task, 0, 5, 1.0)
     rec = RunRecord(**{**record.__dict__, "tickets": broken})
-    bad = correctness.exact(rec, staleness=2, stuck=1)
+    bad = correctness.exact(rec, staleness=2, stuck=1, unchecked=2)
     assert bad["version_gaps"] == 2          # version 4 lost, 5 published twice
     assert bad["ticket_repeats"] == 1
     assert bad["over_stale"] == 1            # computed at 0, applied onto 4
     assert bad["stuck"] == 1
+    assert bad["unchecked"] == 2
     checks = correctness.check(reference, rec, correctness.commit_log(broken),
                                [5], fetched, params0, staleness=2, weight=1.0,
-                               stuck=1, limits=SMALL_LIMITS)
+                               stuck=1, limits=SMALL_LIMITS, unchecked=0)
     assert not all(c["ok"] for c in checks.values())
+    # a sound log with a sample short of what was asked
+    checks = correctness.check(reference, record, commits, [5], fetched,
+                               params0, staleness=2, weight=1.0, stuck=0,
+                               limits=SMALL_LIMITS, unchecked=1)
+    assert [n for n, c in checks.items() if not c["ok"]] == ["unchecked"]
 
 
 def test_sample_holds_the_final_version(served):
     _, record, commits, _, _ = served
-    sample = correctness.sample_commits(record, 7, 3)
-    assert max(sample) == N and len(sample) <= 4
-    assert correctness.versions_needed(commits, sample)[:4] == [0, 1, 2, 3]
+    sample, shortfall = correctness.sample_commits(record, 7, 3,
+                                                   set(range(N + 1)))
+    assert max(sample) == N and len(sample) <= 4 and shortfall == 0
+    assert correctness.FIRST_VERSIONS == (1, 2, 3)
+    needed = correctness.versions_needed(commits, sample)
+    assert all({c - 1, c} - {0} <= set(needed) for c in sample)
+
+
+@pytest.mark.parametrize("held,n,shortfall", [
+    (range(0, N + 1), 3, 0),
+    (range(3, N + 1), 3, 0),        # commits 4 to 6 can be followed
+    (range(4, N + 1), 3, 1),        # only 5 and 6
+    (range(N - 1, N + 1), 3, 2),    # only the final version
+    (range(N, N + 1), 3, 4),        # not even the final version
+    (range(0, N + 1), N + 2, 0),    # the window made fewer than asked
+    (range(4, N + 1), N + 2, N - 2),
+], ids=["all", "last-4", "last-3", "last-2", "last-1", "few-all",
+        "few-last-3"])
+def test_sample_is_drawn_from_what_the_program_holds(served, held, n,
+                                                     shortfall):
+    _, record, commits, _, _ = served
+    sample, short = correctness.sample_commits(record, 7, n, set(held))
+    assert short == shortfall
+    assert set(correctness.versions_needed(commits, sample)) <= set(held)
+    assert (N in sample) == (N - 1 in held)
+
+
+@pytest.mark.parametrize("stand_in", [None, ("high", None)],
+                         ids=["program", "high-control"])
+def test_spilled_readings_equal_in_memory_readings(served, stand_in):
+    reference, record, commits, fetched, params0 = served
+    sample = list(range(FIRST_SAMPLE, N + 1))
+    versions = correctness.Versions()
+    try:
+        for v in sorted(fetched):
+            if v:
+                versions.put(v, fetched[v])
+        assert sorted(versions) == list(range(1, N + 1)) and 0 not in versions
+        spilled = correctness.readings(reference, record, commits, sample,
+                                       versions, params0, weight=1.0,
+                                       stand_in=stand_in)
+    finally:
+        versions.close()
+    in_memory = _readings(served, stand_in=stand_in)
+    assert spilled == in_memory
+    assert not versions._trees and not pathlib.Path(versions._dir.name).exists()
 
 
 @pytest.mark.parametrize("cell", [w["name"] for w in json.loads(
