@@ -12,6 +12,10 @@ configuration states), with one pass, and in float32 with half of each
 mini-batch left out or one label altered (planted faults). Each is judged
 against the cell's committed limits as the program's run is.
 
+Each line also gives the device's ``bytes_in_use`` once the run has
+returned and the collector has run: what a run leaves on the device for
+the next seed's.
+
 ``--look`` adds, for each checked commit, the worst leaf of its one-step
 change and the elements that differ most there, with the reference's
 RMSprop state beside them. ``--keep-trace PATH`` makes the first seed's
@@ -25,6 +29,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import gc  # noqa: E402
 import json  # noqa: E402
 import pathlib  # noqa: E402
 import sys  # noqa: E402
@@ -116,6 +121,10 @@ def main(argv=None) -> int:
             line[name] = {"correct": all(c["ok"] for c in judged.values()),
                           **{n: c["value"] for n, c in judged.items()}}
         line["metrics"] = res["metrics"]
+        del res
+        gc.collect()
+        line["bytes_in_use"] = (devices[0].memory_stats() or {}).get(
+            "bytes_in_use")
         print(json.dumps(line), flush=True)
         t_start = time.perf_counter()
     return 0

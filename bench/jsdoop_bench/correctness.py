@@ -38,6 +38,8 @@ every sampled commit checkable) have the limit 0.
 from __future__ import annotations
 
 import math
+import os
+import sys
 import tempfile
 from collections.abc import Mapping
 from pathlib import Path
@@ -62,18 +64,22 @@ EXACT = ("version_gaps", "ticket_repeats", "over_stale", "stuck",
 class Versions(Mapping):
     """Published versions fetched for the check, version -> host pytree,
     kept on disk leaf by leaf as each arrives, in a temporary directory
-    that ``close()`` removes. Reading a version loads it anew, so the
-    check holds only the versions it is reading."""
+    under ``tempfile``'s (``TMPDIR``) that ``close()`` removes, logging
+    the bytes spilled and the type of the filesystem they went to.
+    Reading a version loads it anew, so the check holds only the versions
+    it is reading."""
 
     def __init__(self):
         self._dir = tempfile.TemporaryDirectory(prefix="bench-versions-")
         self._trees: Dict[int, Any] = {}         # version -> tree structure
+        self.bytes = 0                           # written to the directory
 
     def put(self, version: int, tree) -> None:
         leaves, treedef = jax.tree.flatten(tree)
         for i, leaf in enumerate(leaves):
-            np.save(self._path(version, i), np.asarray(leaf),
-                    allow_pickle=False)
+            path = self._path(version, i)
+            np.save(path, np.asarray(leaf), allow_pickle=False)
+            self.bytes += path.stat().st_size
         self._trees[version] = treedef
 
     def _path(self, version: int, leaf: int) -> Path:
@@ -95,8 +101,30 @@ class Versions(Mapping):
         return len(self._trees)
 
     def close(self) -> None:
+        print(f"versions spilled: {len(self._trees)} versions, {self.bytes} B "
+              f"to {self._dir.name} ({filesystem(self._dir.name)})",
+              file=sys.stderr, flush=True)
         self._trees.clear()
         self._dir.cleanup()
+
+
+def filesystem(path) -> str:
+    """The type of the filesystem ``path`` lies on, as ``/proc/mounts``
+    gives it (``unknown`` where it cannot be read)."""
+    path = os.path.realpath(path)
+    best, kind = "", "unknown"
+    try:
+        with open("/proc/mounts") as f:
+            for line in f:
+                _, mount, fstype = line.split()[:3]
+                mount = mount.replace("\\040", " ")
+                inside = path == mount or path.startswith(
+                    mount.rstrip("/") + "/")
+                if inside and len(mount) >= len(best):
+                    best, kind = mount, fstype
+    except OSError:
+        pass
+    return kind
 
 
 def commit_log(tickets) -> Dict[int, Any]:
@@ -250,12 +278,16 @@ def readings(reference, record, commits, sample, fetched, params0, *,
         if c == 1:
             g_ref = grad_norms(want[1]["ms"], rho)
             grad1_gap = worst_gap(grad_norms(got[1]["ms"], rho), g_ref)
-        steps = (_params_change(want[0], before), _params_change(got[0], before))
+        ref_step = _params_change(want[0], before)
+        prog_step = _params_change(got[0], before)
         if ref_change is None:
-            ref_change, prog_change = steps
+            ref_change, prog_change = ref_step, prog_step
         else:
-            ref_change = [a + b for a, b in zip(ref_change, steps[0])]
-            prog_change = [a + b for a, b in zip(prog_change, steps[1])]
+            for total, step in zip(ref_change + prog_change,
+                                   ref_step + prog_step):
+                total += step
+        # one commit's versions and changes at a time
+        del before, want, got, ref_step, prog_step
     change_gap = worst_gap(_norms(prog_change), _norms(ref_change), kept(g_ref))
     step_gap = 0.0
     for c in sample:
@@ -263,6 +295,7 @@ def readings(reference, record, commits, sample, fetched, params0, *,
         ref_step = change_norms(want[0], before)
         step_gap = max(step_gap, worst_gap(change_norms(got[0], before),
                                            ref_step, kept(ref_step)))
+        del before, want, got
     return {"loss_gap": loss_gap, "grad1_gap": grad1_gap,
             "change_gap": change_gap, "step_gap": step_gap}
 

@@ -120,6 +120,7 @@ class CompileMeter:
         self.ends: List[float] = []
         self.seconds = 0.0
         self.hits = 0
+        self._jax = jax
         jax.monitoring.register_event_duration_secs_listener(self._duration)
         jax.monitoring.register_event_listener(self._event)
 
@@ -134,6 +135,11 @@ class CompileMeter:
 
     def between(self, t0: float, t1: float) -> int:
         return sum(t0 <= t < t1 for t in self.ends)
+
+    def close(self) -> None:
+        """Stop listening: a process that runs many cells keeps no meter."""
+        self._jax.monitoring.unregister_event_duration_listener(self._duration)
+        self._jax.monitoring.unregister_event_listener(self._event)
 
 
 # ---------------------------------------------------------------------------
@@ -277,6 +283,20 @@ def _peak_rss_bytes() -> int:
     return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
 
 
+class SetupPhases:
+    """Set-up phase by phase on standard error: the seconds each took, and
+    the host's peak RSS once it ended."""
+
+    def __init__(self, t_start: float):
+        self._t = t_start
+
+    def done(self, name: str, t: Optional[float] = None) -> None:
+        now = time.perf_counter() if t is None else t
+        _log(f"setup phase {name}: {now - self._t:.3f} s, host peak RSS "
+             f"{_peak_rss_bytes()} B")
+        self._t = now
+
+
 def _log_memory(device) -> Dict[str, int]:
     """Log the device's memory and the process's peak host RSS; returns the
     device's memory stats."""
@@ -315,6 +335,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     # program computes in this process
     jax.config.update("jax_default_matmul_precision", cfg["matmul_precision"])
     meter = CompileMeter(jax)
+    phases = SetupPhases(t_start)
+    phases.done("start (imports, the chip, the compile cache)")
     family = load_family(cfg["family"])
     ref = family.REFERENCE
     seeds = Seeds.of(seed)
@@ -334,20 +356,27 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     if tamper is not None:
         tamper(problem)
     host_params0 = jax.tree.map(np.asarray, params0)
+    phases.done("build (corpus, weights, the program's problem)")
     if kind == "map":
         # every drain length the window can see: one submit per volunteer
         rows_of = np.zeros((n_vol, problem.pack_grads(params0).size),
                            np.float32)
+        per_length = []
         for n in range(1, n_vol + 1):
+            t = time.perf_counter()
             carry = problem.flat_carry(problem.params0, problem.opt_state0)
             _, steps = problem.apply_batch_flat(carry, rows_of[:n], donate=True)
             jax.block_until_ready(problem.unflatten_step(steps, n - 1))
+            per_length.append(time.perf_counter() - t)
+        phases.done(f"warm-up of drain lengths 1-{n_vol} (s each: "
+                    + " ".join(f"{x:.3f}" for x in per_length) + ")")
     server = GatewayServer(problem, n_versions=n_versions, policy=policy,
                            real_apply=True)
     applier = server.applier
     if trace:
         _annotate_server(server, jax)
     serving = server.start()
+    phases.done("server start")
 
     tickets: List[Ticket] = []
     done_by: Dict[str, int] = {}
@@ -424,13 +453,19 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
                 server.ds.latest_version >= correctness.FIRST:
             # as soon as they are published, before the program can drop
             # them; outside the window, so no metric sees it
+            t = time.perf_counter()
             try:
                 first_missing = fetch_versions(
                     server.port, correctness.FIRST_VERSIONS, versions)
             except Exception as e:          # re-raised once all have stopped
                 errors.append(e)
+            _log(f"setup fetch of versions {correctness.FIRST_VERSIONS} "
+                 f"(inside the warm-up commits): "
+                 f"{time.perf_counter() - t:.3f} s")
             continue
         time.sleep(0.005)
+    phases.done(f"warm-up commits ({sum(t.committed > 0 for t in tickets)} "
+                f"committed)")
 
     # everything set-up made lives on through the window: left out of the
     # collector's passes there, so that a full pass scans only what the
@@ -441,6 +476,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     summary = trace_window = None
     t0 = time.perf_counter()
     setup_s = t0 - t_start
+    phases.done("gc freeze", t0)
     counters["t0"] = applier_counters(applier)
     logdir = None
     if not errors and trace:
@@ -457,11 +493,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         th.join(JOIN_TIMEOUT_S)
     stuck = sum(th.is_alive() for th in threads)
     if errors:
+        meter.close()
         _log_memory(devices[0])
         stop_server(server, serving)
         versions.close()
         raise errors[0]
     compiles = meter.between(t0, t1)
+    meter.close()
     if logdir is not None:
         try:
             path = tracemod.find_trace(logdir)

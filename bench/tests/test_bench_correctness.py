@@ -4,11 +4,13 @@ below float32 at ``highest`` (three bfloat16 passes per product), on one
 bfloat16 pass, and on planted faults."""
 import json
 import pathlib
+import time
 
 import jax
 import pytest
 
-from benchtest import SMALL_LIMITS, sequential_log
+from benchtest import (BENCH, SMALL_LIMITS, OverBudget, check_budget,
+                       cpu_config, sequential_log)
 from jsdoop_bench import correctness, spec
 from jsdoop_bench.driver import RunRecord, Ticket
 
@@ -19,7 +21,7 @@ FIRST_SAMPLE = 2
 
 @pytest.fixture(scope="module")
 def served():
-    return sequential_log(CFG, N)
+    return sequential_log(CFG, N, spill=False)
 
 
 def _readings(served, fetched=None, stand_in=None):
@@ -131,16 +133,56 @@ def test_spilled_readings_equal_in_memory_readings(served, stand_in):
     assert not versions._trees and not pathlib.Path(versions._dir.name).exists()
 
 
+@pytest.mark.parametrize("stand_in", [None, ("high", None)],
+                         ids=["program", "high-control"])
+def test_sequential_log_spilled_equals_in_memory(served, stand_in):
+    spilled = sequential_log(CFG, N)
+    fetched = spilled[3]
+    try:
+        assert isinstance(fetched, correctness.Versions)
+        assert sorted(fetched) == list(range(1, N + 1))
+        assert _readings(spilled, stand_in=stand_in) \
+            == _readings(served, stand_in=stand_in)
+    finally:
+        fetched.close()
+
+
 @pytest.mark.parametrize("cell", [w["name"] for w in json.loads(
     (spec.ROOT / "BENCHMARK.json").read_text())["workloads"]])
 def test_control_fails_the_cells_limits_at_full_width(cell):
     """The control, the reference with three bfloat16 passes per product in
     the program's place, at the cell's own widths and batch, judged against
-    the cell's committed limits."""
+    the cell's committed limits. A configuration with a ``"cpu"`` form
+    runs at that form (widths as published, within the CPU budget), and
+    its cells' limits files carry the control's full-size chip reading."""
     c = spec.load_cell(cell)
-    served = sequential_log(c.config, 5, seed=31)
+    served = sequential_log(cpu_config(c.config), 5, seed=31)
     reference, record, commits, fetched, params0 = served
-    r = correctness.readings(reference, record, commits, [4, 5], fetched,
-                             params0, weight=1.0, stand_in=("high", None))
+    try:
+        r = correctness.readings(reference, record, commits, [4, 5], fetched,
+                                 params0, weight=1.0, stand_in=("high", None))
+    finally:
+        fetched.close()
     judged = correctness.judge(r, c.limits)
     assert not all(v["ok"] for v in judged.values()), (r, c.limits)
+
+
+#: paper-lstm at hidden size 4096 (202,137,638 parameters), with the CPU
+#: form of one layer
+WIDE = json.loads((BENCH / "tests" / "fixtures" / "paper-lstm-4096.json")
+                  .read_text())
+
+
+def test_over_the_budget_fails_at_once_naming_the_cpu_form():
+    cfg = {k: v for k, v in WIDE.items() if k != "cpu"}
+    t = time.perf_counter()
+    with pytest.raises(OverBudget, match='"cpu" object') as e:
+        sequential_log(cfg, 5, seed=31)
+    assert time.perf_counter() - t < 1.0
+    assert "202,137,638 parameters" in str(e.value)
+
+
+def test_cpu_form_is_within_the_budget():
+    cfg = cpu_config(WIDE)
+    assert cfg["num_layers"] == 1 and cfg["hidden_size"] == 4096
+    check_budget(cfg)

@@ -5,15 +5,13 @@ import re
 
 import pytest
 
-import benchtest  # noqa: F401  (puts the harness on the path)
+from benchtest import WIDTHS, cpu_config
 from jsdoop_bench import spec
 
 BENCH = json.loads((spec.ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in BENCH["workloads"]]
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
-WIDTHS = re.compile(r"(hidden|intermediate|latent|state|projection|_dim$|"
-                    r"_rank$|head|expansion|experts_per_token)")
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -79,3 +77,38 @@ def test_traffic_mix_is_data(mix):
     m = spec.load_traffic(mix)
     assert m["dialect"] in ("tcp", "ws") and m["volunteers"] >= 1
     assert m["think_ms"] >= 0 and m["checked_commits"] >= 1
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_limits_carry_the_controls_reading(cell):
+    """The control's reading on the chip at the cell's full size. For a
+    configuration with a ``"cpu"`` form it is the only one: the CPU test
+    runs the control at that form."""
+    limits = json.loads((spec.BENCH_DIR / "limits" / f"{cell}.json")
+                        .read_text())
+    assert limits["readings"]["control"].strip(), cell
+
+
+@pytest.mark.parametrize("extra,form,error", [
+    ({}, {"num_layers": 1, "sample_len": 8}, None),
+    ({}, {"hidden_size": 8}, "a width"),
+    ({"sliding_window": 1024}, {"sliding_window": 64}, "a width"),
+    ({}, {"num_experts": 1}, "does not have"),
+], ids=["applied", "width", "window", "unknown"])
+def test_cpu_form(extra, form, error):
+    base = dict(spec.load_config("paper-lstm"), **extra)
+    cfg = dict(base, cpu=form)
+    if error is None:
+        assert cpu_config(cfg) == dict(base, **form)
+        assert cpu_config(base) == base
+    else:
+        with pytest.raises(spec.SpecError, match=error):
+            cpu_config(cfg)
+
+
+@pytest.mark.parametrize("key", ["hidden_size", "intermediate_size",
+                                 "moe_intermediate_size", "head_dim",
+                                 "sliding_window", "num_experts_per_tok",
+                                 "kv_lora_rank"])
+def test_widths_are_never_cut(key):
+    assert WIDTHS.search(key)
