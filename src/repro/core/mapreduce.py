@@ -28,6 +28,42 @@ from repro.models.runtime import Runtime
 from repro.optim import Optimizer, rmsprop, dense_bytes
 
 
+def _word_layout(arrays):
+    """((dtype, shape) of each array, the unsigned word they pack into). The
+    word is as wide as the narrowest array, so every array bit-casts into
+    whole words: uint32 for float32 arrays beside an int32 or a float32."""
+    layout = tuple((np.dtype(a.dtype), tuple(a.shape)) for a in arrays)
+    word = np.dtype(f"uint{8 * min(dt.itemsize for dt, _ in layout)}")
+    return layout, word
+
+
+def _pack_words(arrays):
+    """Every array's bits, in order, as one buffer of unsigned words (traced
+    inside a device program). A bit-cast is no float operation, so no
+    integer's bits pass through float arithmetic and no denormal is
+    flushed."""
+    word = _word_layout(arrays)[1]
+    return jnp.concatenate(
+        [jax.lax.bitcast_convert_type(a, word).ravel() for a in arrays])
+
+
+def _unpack_words(words: np.ndarray, layout, word):
+    """The arrays of a ``_pack_words`` buffer copied to the host, as
+    read-only NumPy views of it, one per (dtype, shape) of ``layout``:
+    split, bit-cast back and reshaped, so every bit is the device's."""
+    words.flags.writeable = False
+    counts = [int(np.prod(shape)) * dt.itemsize // word.itemsize
+              for dt, shape in layout]
+    return [seg.view(dt).reshape(shape) for seg, (dt, shape) in
+            zip(np.split(words, np.cumsum(counts)[:-1]), layout)]
+
+
+@jax.jit
+def _pack_grad(loss, leaves):
+    # a named program, so a profile shows it as jit__pack_grad
+    return _pack_words([loss, *leaves])
+
+
 @dataclass
 class TrainingProblem:
     cfg: Any                     # ArchConfig (vocab resolved)
@@ -112,12 +148,31 @@ class TrainingProblem:
         return divmod(i % (self.n_versions * n_mb), n_mb)
 
     # ------------------------------------------------------------------ compute
+    def _step(self, params, version: int, mb_index: int):
+        """(loss, grads) of one mini-batch, left on the device."""
+        return self._grad_fn(params, self.minibatch(version, mb_index))
+
     def map_compute(self, params, version: int, mb_index: int):
-        """Returns (grads, loss)."""
+        """Returns (grads, loss), the gradient where ``params`` live.
+
+        Parameters on the host (a model fetched over the wire, whose
+        gradient goes back over it): one device program packs the loss and
+        every gradient leaf into one buffer of words, ONE device-to-host
+        transfer brings it over, and the gradient is read-only NumPy views
+        of that buffer, bit for bit the device's (the encoder of
+        ``SubmitUpdate`` then copies nothing from the device). Parameters
+        on the device (a caller that applies the gradient in this process):
+        the gradient stays there."""
         with obs.span("repro.step"):
-            batch = self.minibatch(version, mb_index)
-            loss, grads = self._grad_fn(params, batch)
-            return grads, float(loss)
+            loss, grads = self._step(params, version, mb_index)
+            if any(isinstance(x, jax.Array) for x in jax.tree.leaves(params)):
+                return grads, float(loss)
+            leaves, treedef = jax.tree.flatten(grads)
+            layout = _word_layout([loss, *leaves])
+            with obs.span("repro.grad_to_host"):
+                words = np.asarray(_pack_grad(loss, leaves))
+            loss, *leaves = _unpack_words(words, *layout)
+            return treedef.unflatten(leaves), float(loss)
 
     def reduce_compute(self, params, opt_state, grads_by_mb: Dict[int, Any]):
         """grads_by_mb: mb_index -> grads. Deterministic order via sort."""
@@ -136,17 +191,20 @@ class TrainingProblem:
 
     def local_compute(self, params, opt_state, start: int, k: int):
         """LocalSteps ticket: k local optimizer steps from stream offset
-        ``start``. Returns ((delta_params, delta_opt_state), mean_loss)."""
+        ``start``. Returns ((delta_params, delta_opt_state), mean_loss).
+        The gradients stay on the device; the k losses reach the host
+        together, after the last step."""
         p0, s0 = params, opt_state
-        losses: List[float] = []
+        losses = []
         for j in range(k):
-            v, mb = self.stream_slot(start + j)
-            g, l = self.map_compute(params, v, mb)
+            with obs.span("repro.step"):
+                l, g = self._step(params, *self.stream_slot(start + j))
             params, opt_state = self._apply_one_fn(params, opt_state, g)
             losses.append(l)
         delta = jax.tree.map(lambda a, b: a - b, (params, opt_state),
                              (p0, s0))
-        return delta, float(np.mean(losses))
+        return delta, float(np.mean([float(l)
+                                     for l in jax.device_get(losses)]))
 
     def apply_delta(self, params, opt_state, delta, weight: float = 1.0, *,
                     donate: bool = False):
@@ -269,40 +327,24 @@ class TrainingProblem:
 
     @functools.cached_property
     def _row_layout(self):
-        """((dtype, shape) of each carry part, the unsigned word of a packed
-        row). The word is as wide as the narrowest part, so every part
-        bit-casts into whole words: uint32 for float32 weights and an int32
-        step."""
-        parts = self._carry_parts(jax.eval_shape(
-            self.flat_carry, self.params0, self.opt_state0))
-        word = np.dtype(f"uint{8 * min(p.dtype.itemsize for p in parts)}")
-        return tuple((np.dtype(p.dtype), p.shape) for p in parts), word
-
-    def _pack_row(self, steps, i):
-        word = self._row_layout[1]
-        return jnp.concatenate(
-            [jax.lax.bitcast_convert_type(a[i], word).ravel()
-             for a in self._carry_parts(steps)])
+        """``_word_layout`` of a packed row's carry parts: uint32 words for
+        float32 weights and an int32 step."""
+        return _word_layout(self._carry_parts(jax.eval_shape(
+            self.flat_carry, self.params0, self.opt_state0)))
 
     @functools.cached_property
     def _pack_step_fn(self):
         # one program slices row ``i`` of every part and bit-casts it into
-        # one buffer of words: a bit-cast is no float operation, so no
-        # integer's bits pass through float arithmetic and no denormal is
-        # flushed. ``i`` traces as a dynamic scalar, so one compilation
-        # serves every step index (retraced only per drain length)
-        return jax.jit(lambda steps, i: self._pack_row(steps, i))
+        # one buffer of words. ``i`` traces as a dynamic scalar, so one
+        # compilation serves every step index (retraced only per drain
+        # length)
+        return jax.jit(lambda steps, i: _pack_words(
+            [a[i] for a in self._carry_parts(steps)]))
 
     def _unpack_row(self, words: np.ndarray):
-        """(params, opt_state) as read-only NumPy views of a packed row:
-        split, bit-cast back and reshaped, so every bit is the device's."""
+        """(params, opt_state) as read-only NumPy views of a packed row."""
         treedef, shapes, sizes, _, tree_keys, scalar_keys = self._flat_spec
-        layout, word = self._row_layout
-        words.flags.writeable = False
-        counts = [int(np.prod(shape)) * dt.itemsize // word.itemsize
-                  for dt, shape in layout]
-        parts = [seg.view(dt).reshape(shape) for seg, (dt, shape) in
-                 zip(np.split(words, np.cumsum(counts)[:-1]), layout)]
+        parts = _unpack_words(words, *self._row_layout)
         leaf_ends = np.cumsum(sizes)[:-1]
 
         def tree(vec):

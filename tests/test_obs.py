@@ -16,7 +16,7 @@ SERVED_SPANS = {"repro.lock_wait", "repro.lock_held", "repro.serve",
                 "repro.materialize", "repro.drain", "repro.admit",
                 "repro.pack", "repro.apply", "repro.publish", "repro.encode",
                 "repro.decode", "repro.to_host", "repro.send", "repro.call",
-                "repro.step"}
+                "repro.step", "repro.grad_to_host"}
 
 
 @pytest.fixture
@@ -209,3 +209,6 @@ def test_served_run_records_every_span(on):
     assert got["repro.drain"].attrs["n"].sum() == \
         server.applier.applied + server.applier.rejected
     assert len(got["repro.step"]) >= 16
+    # each gradient's one transfer to the host lies inside its step
+    assert set(got["repro.grad_to_host"].parent_name) == {"repro.step"}
+    assert len(got["repro.grad_to_host"]) == len(got["repro.step"])

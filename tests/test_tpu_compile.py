@@ -111,6 +111,18 @@ def test_paper_grad_step_compiles(one_chip, paper_problem):
                      _on_chip(batch, one_chip)).compile()
 
 
+def test_paper_grad_pack_compiles(one_chip, paper_problem):
+    """The program that packs the volunteer's loss and gradient leaves into
+    the one buffer a step copies to the host."""
+    from repro.core.mapreduce import _pack_grad
+    p = paper_problem
+    loss, grads = jax.eval_shape(p._grad_fn, p.params0, p.minibatch(0, 0))
+    leaves = _on_chip(jax.tree.leaves(grads), one_chip)
+    out = _pack_grad.lower(_on_chip(loss, one_chip), leaves)
+    assert out.out_info.shape == (1 + sum(x.size for x in leaves),)
+    out.compile()
+
+
 def test_paper_applier_scan_compiles(one_chip, paper_problem):
     """The applier's donated scan over a drain of 16 flat gradient rows."""
     p = paper_problem
